@@ -1,8 +1,9 @@
-"""Kernel micro-benchmarks: Pallas (interpret) vs jnp-oracle parity + timing.
+"""Kernel micro-benchmarks: Pallas vs jnp-oracle parity + timing.
 
 Wall times on CPU measure the oracle path (the deployment path off-TPU);
-the Pallas interpret runs validate numerics at benchmark shapes. On TPU the
-same harness times the real kernels (force="pallas", interpret off).
+the Pallas kernels run interpreted there and validate numerics at benchmark
+shapes. On a TPU they compile instead (a kernel never runs interpreted on
+the chip), and the ELL SpMV/SpMM kernels do not lower there yet (ROADMAP S0).
 """
 
 from __future__ import annotations
@@ -23,6 +24,7 @@ from .common import emit, timed, timed_aot
 
 
 def run() -> None:
+    interpret = jax.default_backend() != "tpu"
     key = jax.random.PRNGKey(0)
     # flash attention at a serving-ish shape
     B, Sq, Skv, Hq, Hkv, Dh = 2, 256, 256, 8, 2, 64
@@ -31,7 +33,8 @@ def run() -> None:
     k = jax.random.normal(ks[1], (B, Skv, Hkv, Dh))
     v = jax.random.normal(ks[2], (B, Skv, Hkv, Dh))
     refo, us = timed(lambda: np.asarray(ref.flash_attention_ref(q, k, v)))
-    pal = flash_attention_pallas(q, k, v)
+    pal = flash_attention_pallas(q, k, v,
+                                 interpret=interpret)
     err = float(jnp.abs(pal - refo).max())
     emit("kernels/flash_attention", us, f"maxerr={err:.2e};shape=B{B}S{Sq}H{Hq}")
 
@@ -42,7 +45,7 @@ def run() -> None:
     w = jax.random.normal(ks[2], (n, K))
     x = jax.random.normal(key, (n,))
     refo, us = timed(lambda: np.asarray(ref.ell_spmv_ref(nbr, msk, x, w)))
-    pal = ell_spmv_pallas(nbr, msk, w, x)
+    pal = ell_spmv_pallas(nbr, msk, w, x, interpret=interpret)
     err = float(jnp.abs(pal - refo).max())
     emit("kernels/ell_spmv", us, f"maxerr={err:.2e};n={n};K={K}")
 
@@ -50,7 +53,7 @@ def run() -> None:
     Bq = 8
     xb = jax.random.normal(key, (Bq, n))
     refo, us = timed(lambda: np.asarray(ref.ell_spmm_ref(nbr, msk, xb, w)))
-    pal = ell_spmm_pallas(nbr, msk, w, xb)
+    pal = ell_spmm_pallas(nbr, msk, w, xb, interpret=interpret)
     err = float(jnp.abs(pal - refo).max())
     emit("kernels/ell_spmm", us, f"maxerr={err:.2e};n={n};K={K};B={Bq}")
     # device-time row (jax.profiler-backed AOT harness, DESIGN.md §15):
@@ -64,7 +67,8 @@ def run() -> None:
     thr = jnp.abs(jax.random.normal(ks[1], (n,))) * 0.1
     refo, us = timed(lambda: np.asarray(
         ref.ell_spmm_ref(nbr, msk, xb, w, threshold=thr)))
-    pal = ell_spmm_pallas(nbr, msk, w, xb, thr)
+    pal = ell_spmm_pallas(nbr, msk, w, xb, thr,
+                          interpret=interpret)
     err = float(jnp.abs(pal - refo).max())
     emit("kernels/ell_spmm_fused_push", us,
          f"maxerr={err:.2e};n={n};K={K};B={Bq}")
@@ -86,7 +90,8 @@ def run() -> None:
     s_w, s_map = jnp.asarray(sl.weights), jnp.asarray(sl.row_map)
     refo, us = timed(lambda: np.asarray(ref.ell_spmm_sliced_ref(
         s_nbr, s_msk, xp, s_w, row_map=s_map)))
-    pal = ell_spmm_sliced_pallas(s_nbr, s_msk, s_w, s_map, xp)
+    pal = ell_spmm_sliced_pallas(s_nbr, s_msk, s_w, s_map, xp,
+                                 interpret=interpret)
     err = float(jnp.abs(pal - refo).max())
     emit("kernels/ell_spmm_sliced", us,
          f"maxerr={err:.2e};n={n_pl};W={sl.width};nv={sl.n_virtual};B={Bq}")
@@ -99,7 +104,7 @@ def run() -> None:
     # order), plus speedup vs the eager oracle row above. Timing is AOT
     # device time on the jitted dispatch — compile cost is its own field.
     yT_part = _spmm_virtual_rows(s_nbr, s_msk, s_w, xp, None,
-                                 block_n=256, interpret=True)
+                                 block_n=256, interpret=interpret)
     old_fold = jax.ops.segment_sum(
         yT_part[:sl.n_virtual], s_map, num_segments=n_pl,
         indices_are_sorted=True).T
@@ -122,7 +127,8 @@ def run() -> None:
     ids = jax.random.randint(ks[1], (Bb, L), 0, V)
     wts = jax.random.uniform(ks[2], (Bb, L))
     refo, us = timed(lambda: np.asarray(ref.embedding_bag_ref(table, ids, wts)))
-    pal = embedding_bag_pallas(table, ids, wts)
+    pal = embedding_bag_pallas(table, ids, wts,
+                               interpret=interpret)
     err = float(jnp.abs(pal - refo).max())
     emit("kernels/embedding_bag", us, f"maxerr={err:.2e};V={V};B={Bb};L={L}")
 
@@ -135,7 +141,8 @@ def run() -> None:
     w_lanes = jax.random.uniform(key, (Bq, W_wi))
     refo, us = timed(lambda: np.asarray(ref.walk_endpoint_gather_ref(
         endpoints, budget, starts, w_lanes)))
-    pal = walk_endpoint_gather_pallas(endpoints, budget, starts, w_lanes)
+    pal = walk_endpoint_gather_pallas(endpoints, budget, starts, w_lanes,
+                                      interpret=interpret)
     err = float(jnp.abs(pal - refo).max())
     emit("kernels/walk_endpoint_gather", us,
          f"maxerr={err:.2e};n={n_wi};W={W_wi};B={Bq}")

@@ -50,8 +50,10 @@ class RuntimeStats:
 
     @property
     def t_avg(self) -> float:
-        """mean t_i  (Alg. 2 Line 2)."""
-        return float(self.times.mean())
+        """mean t_i  (Alg. 2 Line 2), clipped to [min, max] so float
+        rounding of equal samples cannot put it above ``t_max``."""
+        t = self.times
+        return float(np.clip(t.mean(), t.min(), t.max()))
 
     @property
     def t_pre(self) -> float:

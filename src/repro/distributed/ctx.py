@@ -106,15 +106,3 @@ def active_mesh() -> Mesh | None:
     ctx = _current()
     return ctx[0] if ctx else None
 
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: older releases keep it in
-    jax.experimental.shard_map and spell ``check_vma`` as ``check_rep``."""
-    try:
-        from jax import shard_map as _shard_map
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=check_vma)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)

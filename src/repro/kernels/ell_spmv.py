@@ -36,6 +36,12 @@ only the row axis is virtualised.
 
 Also used by the GNN SpMM regime (GCN's \\hat{A} X when X is a vector batch).
 Validated in interpret mode against ref.ell_spmv_ref / ref.ell_spmm_ref.
+
+These kernels do not lower for the TPU yet: Mosaic refuses the in-kernel
+``jnp.take`` gather from the VMEM-resident x (at any n), and at Table-I n
+that resident x would not fit VMEM anyway (ROADMAP S0). So
+:mod:`repro.kernels.ops` runs the SpMMs as XLA on the TPU, and these bodies
+run only interpreted, under ``force="pallas"`` off the TPU.
 """
 
 from __future__ import annotations
@@ -45,31 +51,33 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+
+def _lane_chunk(c, chunk: int):
+    """Columns [c*chunk, (c+1)*chunk) of a (rows, Kp) block, as a ref slice."""
+    return pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
 
 
 def _ell_kernel(nbr_ref, mask_ref, w_ref, x_ref, y_ref, *, k_chunks: int,
                 chunk: int):
-    nbr = nbr_ref[...]                                # (bn, Kp) int32
-    msk = mask_ref[...]                               # (bn, Kp) bool
     x = x_ref[...]                                    # (n,) f32 (vector)
 
     def body(c, acc):
-        start = c * chunk
-        idx = jax.lax.dynamic_slice_in_dim(nbr, start, chunk, axis=1)
+        cols = _lane_chunk(c, chunk)
+        idx = nbr_ref[:, cols]                        # (bn, chunk) int32
         vals = jnp.take(x, idx, axis=0)               # VMEM gather
-        wts = (jax.lax.dynamic_slice_in_dim(w_ref[...], start, chunk, axis=1)
-               * jax.lax.dynamic_slice_in_dim(msk, start, chunk, axis=1
-                                              ).astype(vals.dtype))
+        wts = w_ref[:, cols] * mask_ref[:, cols].astype(vals.dtype)
         return acc + jnp.sum(vals * wts, axis=1)
 
-    acc0 = jnp.zeros((nbr.shape[0],), jnp.float32)
+    acc0 = jnp.zeros((nbr_ref.shape[0],), jnp.float32)
     y_ref[...] = jax.lax.fori_loop(0, k_chunks, body, acc0)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_n", "interpret"))
-def ell_spmv_pallas(neighbors, mask, weights, x, *, block_n: int = 256,
-                    interpret: bool = True):
+def ell_spmv_pallas(neighbors, mask, weights, x, *, interpret: bool,
+                    block_n: int = 256):
     """y[i] = sum_j mask*w*x[neighbors[i,j]].  neighbors/mask/weights: (n,K);
     x: (n,) float32. Returns (n,) float32."""
     n, K = neighbors.shape
@@ -109,23 +117,19 @@ def _spmm_partials(nbr_ref, mask_ref, w_ref, xT_ref, thr_ref, *,
     """(bn, B) per-row partial sums — the shared SpMM body. Bit-identical
     between the dense and sliced-fold kernels by construction (DESIGN.md §15:
     the in-kernel fold only changes where partials land, never their value)."""
-    nbr = nbr_ref[...]                                # (bn, Kp) int32
-    msk = mask_ref[...]                               # (bn, Kp) bool
     xT = xT_ref[...]                                  # (n, B) f32, B on lanes
 
     def body(c, acc):
-        start = c * chunk
-        idx = jax.lax.dynamic_slice_in_dim(nbr, start, chunk, axis=1)
+        cols = _lane_chunk(c, chunk)
+        idx = nbr_ref[:, cols]                        # (bn, chunk) int32
         vals = jnp.take(xT, idx, axis=0)              # (bn, chunk, B) gather
         if fuse_threshold:
             thr = jnp.take(thr_ref[...], idx, axis=0)  # (bn, chunk)
             vals = jnp.where(vals > thr[..., None], vals, 0.0)
-        wts = (jax.lax.dynamic_slice_in_dim(w_ref[...], start, chunk, axis=1)
-               * jax.lax.dynamic_slice_in_dim(msk, start, chunk, axis=1
-                                              ).astype(vals.dtype))
+        wts = w_ref[:, cols] * mask_ref[:, cols].astype(vals.dtype)
         return acc + jnp.sum(vals * wts[..., None], axis=1)
 
-    acc0 = jnp.zeros((nbr.shape[0], xT.shape[1]), jnp.float32)
+    acc0 = jnp.zeros((nbr_ref.shape[0], xT.shape[1]), jnp.float32)
     return jax.lax.fori_loop(0, k_chunks, body, acc0)
 
 
@@ -139,7 +143,7 @@ def _ell_spmm_kernel(nbr_ref, mask_ref, w_ref, xT_ref, thr_ref, yT_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("block_n", "interpret"))
 def ell_spmm_pallas(neighbors, mask, weights, x, threshold=None, *,
-                    block_n: int = 256, interpret: bool = True):
+                    interpret: bool, block_n: int = 256):
     """Batched pull-form SpMM: y[b, i] = sum_j mask*w*x[b, neighbors[i,j]].
 
     neighbors/mask/weights: (n, K); x: (B, n) float32 — the batch rides the
@@ -198,32 +202,30 @@ def _spmm_virtual_rows(neighbors, mask, weights, x, threshold, *,
 
 
 def _ell_spmm_fold_kernel(nbr_ref, mask_ref, w_ref, rm_ref, xT_ref, thr_ref,
-                          yT_ref, *, k_chunks: int, chunk: int,
+                          yT_ref, part_ref, *, k_chunks: int, chunk: int,
                           fuse_threshold: bool, bn: int):
     """Sliced-ELL SpMM with the virtual-row fold fused in (DESIGN.md §15).
 
     The (n+1, B) output block has a constant index map, so it stays resident
     across the sequential grid steps: step 0 zeroes it, every step adds its
-    block's per-virtual-row partials onto real rows one virtual row at a
-    time, in ascending virtual-row order. ``row_map`` is sorted ascending,
-    so this is the exact f32 left-fold a sorted ``segment_sum`` performs —
-    bit-identical to the former host-side fold. Padded virtual rows carry
-    row_map == n and land on the dump row the wrapper slices off.
+    block's per-virtual-row partials (staged in the ``part_ref`` scratch)
+    onto real rows one virtual row at a time, in ascending virtual-row
+    order. ``row_map`` is sorted ascending, so this is the exact f32
+    left-fold a sorted ``segment_sum`` performs — bit-identical to the
+    former host-side fold. Padded virtual rows carry row_map == n and land
+    on the dump row the wrapper slices off.
     """
     @pl.when(pl.program_id(0) == 0)
     def _zero():
         yT_ref[...] = jnp.zeros(yT_ref.shape, jnp.float32)
 
-    partial = _spmm_partials(nbr_ref, mask_ref, w_ref, xT_ref, thr_ref,
-                             k_chunks=k_chunks, chunk=chunk,
-                             fuse_threshold=fuse_threshold)
-    rm = rm_ref[...]                                  # (bn,) int32 ascending
+    part_ref[...] = _spmm_partials(nbr_ref, mask_ref, w_ref, xT_ref, thr_ref,
+                                   k_chunks=k_chunks, chunk=chunk,
+                                   fuse_threshold=fuse_threshold)
 
     def fold(j, carry):
-        row = rm[j]
-        cur = pl.load(yT_ref, (pl.dslice(row, 1), slice(None)))
-        pl.store(yT_ref, (pl.dslice(row, 1), slice(None)),
-                 cur + partial[j][None, :])
+        row = rm_ref[j]                               # ascending real row
+        yT_ref[pl.ds(row, 1), :] += part_ref[pl.ds(j, 1), :]
         return carry
 
     jax.lax.fori_loop(0, bn, fold, 0)
@@ -232,8 +234,8 @@ def _ell_spmm_fold_kernel(nbr_ref, mask_ref, w_ref, rm_ref, xT_ref, thr_ref,
 @functools.partial(jax.jit,
                    static_argnames=("block_n", "interpret"))
 def ell_spmm_sliced_pallas(neighbors, mask, weights, row_map, x,
-                           threshold=None, *, block_n: int = 256,
-                           interpret: bool = True):
+                           threshold=None, *, interpret: bool,
+                           block_n: int = 256):
     """Sliced-ELL pull-form SpMM with in-kernel fold (DESIGN.md §8, §15).
 
     neighbors/mask/weights: (n_virtual, W) — virtual rows from
@@ -285,6 +287,7 @@ def ell_spmm_sliced_pallas(neighbors, mask, weights, row_map, x,
         # resident) across every sequential grid step; row n is the dump row
         out_specs=pl.BlockSpec((n + 1, B), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((n + 1, B), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bn, B), jnp.float32)],
         interpret=interpret,
     )(neighbors, mask, weights.astype(jnp.float32),
       row_map.astype(jnp.int32), x.astype(jnp.float32).T,

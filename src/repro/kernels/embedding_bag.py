@@ -37,8 +37,8 @@ def _bag_kernel(table_ref, ids_ref, w_ref, out_ref, *, L: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def embedding_bag_pallas(table, ids, weights, *, block_b: int = 128,
-                         interpret: bool = True):
+def embedding_bag_pallas(table, ids, weights, *, interpret: bool,
+                         block_b: int = 128):
     """table: (V, d) f32; ids: (B, L) int32; weights: (B, L). -> (B, d)."""
     B, L = ids.shape
     V, d = table.shape

@@ -80,9 +80,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(
     jax.jit,
     static_argnames=("causal", "q_offset", "block_q", "block_k", "interpret"))
-def flash_attention_pallas(q, k, v, *, causal: bool = True, q_offset: int = 0,
-                           block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+def flash_attention_pallas(q, k, v, *, interpret: bool, causal: bool = True,
+                           q_offset: int = 0, block_q: int = 128,
+                           block_k: int = 128):
     """q: (B, Sq, Hq, Dh); k/v: (B, Skv, Hkv, Dh); Hq % Hkv == 0.
 
     Returns (B, Sq, Hq, Dh) in q.dtype. Sq/Skv are padded to block multiples

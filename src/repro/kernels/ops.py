@@ -1,7 +1,13 @@
-"""Jit'd dispatch wrappers: Pallas on TPU, jnp oracle elsewhere.
+"""Jit'd dispatch wrappers: the Pallas kernel where it lowers for the TPU,
+the jnp (XLA) oracle elsewhere.
 
-Call sites use these; the backend decision happens once at trace time.
-``force`` overrides for tests ("pallas" exercises interpret mode on CPU).
+Call sites use these; the backend decision happens once at trace time and
+is recorded in :data:`IMPLS`. The rule is unconditional and per op: on the
+TPU an op runs its compiled Pallas kernel iff it is in
+:data:`TPU_KERNELS`; every other op runs as XLA there. Off the TPU every op
+runs as XLA. ``force="pallas"`` overrides for tests: it runs the kernel,
+interpreted off the TPU and compiled on it — a kernel never runs in
+interpret mode on the TPU.
 """
 
 from __future__ import annotations
@@ -18,18 +24,40 @@ from .embedding_bag import embedding_bag_pallas
 from .flash_attention import flash_attention_pallas
 from .walk_gather import walk_endpoint_gather_pallas
 
+# Ops whose Pallas kernel compiles for the TPU (tests/test_tpu_compile.py).
+# The ELL SpMV/SpMM kernels are not among them: Mosaic refuses their
+# in-kernel gather from a VMEM-resident x at any n, and that x outgrows VMEM
+# at Table-I n (ROADMAP S0) — so on the TPU the push sweep runs as XLA.
+# ``embedding_bag``'s body still slices loaded values (``dynamic_slice``),
+# which Mosaic does not lower either.
+TPU_KERNELS = frozenset({"walk_endpoint_gather", "flash_attention"})
+
+# op name -> implementation its last trace chose: "pallas" (compiled for
+# the TPU), "pallas-interpret" (force="pallas" off the TPU) or "xla"
+IMPLS: dict[str, str] = {}
+
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:          # noqa: BLE001
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def tpu_kernel(op: str) -> bool:
+    """Whether ``op`` runs its compiled Pallas kernel by default: on the
+    TPU, for the ops in :data:`TPU_KERNELS`."""
+    return _on_tpu() and op in TPU_KERNELS
+
+
+def _use_pallas(op: str, force: str | None) -> bool:
+    """Apply the dispatch rule for ``op`` and record the choice."""
+    use = force == "pallas" or (force is None and tpu_kernel(op))
+    IMPLS[op] = ("pallas" if _on_tpu() else "pallas-interpret") if use \
+        else "xla"
+    return use
 
 
 def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
                     force: str | None = None):
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
+    if _use_pallas("flash_attention", force):
         return flash_attention_pallas(q, k, v, causal=causal,
                                       q_offset=q_offset,
                                       interpret=not _on_tpu())
@@ -37,8 +65,7 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
 
 
 def ell_spmv(neighbors, mask, weights, x, *, force: str | None = None):
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
+    if _use_pallas("ell_spmv", force):
         return ell_spmv_pallas(neighbors, mask, weights, x,
                                interpret=not _on_tpu())
     return ref.ell_spmv_ref(neighbors, mask, x, weights)
@@ -50,8 +77,7 @@ def ell_spmm(neighbors, mask, weights, x, *, threshold=None,
     condition into the gather (see ell_spmv.ell_spmm_pallas). ``block_n``
     is the Pallas row-tile (autotunable, numerics-neutral — DESIGN.md §15);
     the jnp oracle ignores it."""
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
+    if _use_pallas("ell_spmm", force):
         return ell_spmm_pallas(neighbors, mask, weights, x, threshold,
                                block_n=block_n, interpret=not _on_tpu())
     return ref.ell_spmm_ref(neighbors, mask, x, weights, threshold)
@@ -64,8 +90,7 @@ def ell_spmm_sliced(neighbors, mask, weights, row_map, x, *, threshold=None,
     :func:`ell_spmm` on graphs whose dense (n, k_max) table would not fit
     memory. ``block_n`` tiles virtual rows (autotunable, numerics-neutral);
     the jnp oracle ignores it."""
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
+    if _use_pallas("ell_spmm_sliced", force):
         return ell_spmm_sliced_pallas(neighbors, mask, weights, row_map, x,
                                       threshold, block_n=block_n,
                                       interpret=not _on_tpu())
@@ -108,16 +133,14 @@ def walk_endpoint_gather(endpoints, budget, starts, weights, *,
     residual-weighted endpoint mass onto the (B, n) PPR frame — the walk
     phase without walking. Lanes whose start node's stored ``budget`` does
     not cover them contribute zero (the live shortfall draw owns them)."""
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
+    if _use_pallas("walk_endpoint_gather", force):
         return walk_endpoint_gather_pallas(endpoints, budget, starts,
                                            weights, interpret=not _on_tpu())
     return ref.walk_endpoint_gather_ref(endpoints, budget, starts, weights)
 
 
 def embedding_bag(table, ids, weights, *, force: str | None = None):
-    use_pallas = force == "pallas" or (force is None and _on_tpu())
-    if use_pallas:
+    if _use_pallas("embedding_bag", force):
         return embedding_bag_pallas(table, ids, weights,
                                     interpret=not _on_tpu())
     return ref.embedding_bag_ref(table, ids, weights)

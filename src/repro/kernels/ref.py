@@ -54,12 +54,13 @@ def ell_spmm_ref(neighbors, mask, x, weights=None, threshold=None):
     where f is identity, or — with ``threshold`` (n,) — FORA's fused push
     selection f(v) = v * [v > threshold[src]] (DESIGN.md §7): feeding the raw
     residual r and the per-node push threshold yields P^T (r * front) without
-    materialising the frontier between sweeps.
+    materialising the frontier between sweeps. Here the selection is
+    applied to x before the gather — the same values, with one (n, K)
+    gather per call instead of two.
     """
-    gathered = x[:, neighbors]                    # (B, n, K)
     if threshold is not None:
-        thr = threshold[neighbors]                # (n, K) per-source bound
-        gathered = jnp.where(gathered > thr[None, :, :], gathered, 0.0)
+        x = jnp.where(x > threshold[None, :], x, 0.0)
+    gathered = x[:, neighbors]                    # (B, n, K)
     w = mask.astype(x.dtype)
     if weights is not None:
         w = w * weights.astype(x.dtype)

@@ -22,6 +22,8 @@ output rows are VMEM-tiled in blocks of ``block_n`` and each block
 accumulates a compare-and-sum one-hot contraction over 128-lane chunks
 (endpoint ids vs the block's node iota), keeping the (B, chunk, block_n)
 compare/multiply on the VPU instead of serialising a segment scatter.
+Lane chunks are sliced from the refs (``pl.ds``), which Mosaic lowers; the
+kernel compiles for v5e at Table-I n (``tests/test_tpu_compile.py``).
 Validated in interpret mode against :func:`repro.kernels.ref.walk_endpoint_gather_ref`.
 """
 
@@ -36,26 +38,25 @@ from jax.experimental import pallas as pl
 
 def _gather_kernel(e_ref, w_ref, out_ref, *, l_chunks: int, chunk: int,
                    bn: int):
-    e = e_ref[...]                                  # (B, Lp) int32 endpoints
-    w = w_ref[...]                                  # (B, Lp) f32 weights
+    # e_ref: (B, Lp) int32 endpoints; w_ref: (B, Lp) f32 weights
     base = pl.program_id(0) * bn
     # node ids of this output block, on the lane axis of the compare
     t_ids = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bn), 2)
 
     def body(c, acc):
-        start = c * chunk
-        ec = jax.lax.dynamic_slice_in_dim(e, start, chunk, axis=1)
-        wc = jax.lax.dynamic_slice_in_dim(w, start, chunk, axis=1)
+        lanes = pl.ds(pl.multiple_of(c * chunk, chunk), chunk)
+        ec = e_ref[:, lanes]
+        wc = w_ref[:, lanes]
         onehot = (ec[:, :, None] == t_ids).astype(jnp.float32)  # (B, c, bn)
         return acc + jnp.sum(wc[:, :, None] * onehot, axis=1)
 
-    acc0 = jnp.zeros((e.shape[0], bn), jnp.float32)
+    acc0 = jnp.zeros((e_ref.shape[0], bn), jnp.float32)
     out_ref[...] = jax.lax.fori_loop(0, l_chunks, body, acc0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def walk_endpoint_gather_pallas(endpoints, budget, starts, weights, *,
-                                block_n: int = 256, interpret: bool = True):
+                                interpret: bool, block_n: int = 256):
     """Aggregate stored walk endpoints weighted by push residuals.
 
     endpoints: (n, W) int32 pre-drawn endpoint table; budget: (n,) int32

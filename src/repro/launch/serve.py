@@ -55,7 +55,9 @@ def _print_mesh_plan(cores: int, max_lanes: int) -> None:
     print(f"  cores->mesh        : {plan} on {jax.default_backend()}")
 
 
-def serve_ppr(args) -> None:
+def serve_ppr(args):
+    """The one-shot D&A_REAL pipeline over FORA queries; returns the
+    ``(ForaExecutor, DnaResult)`` it ran."""
     import jax
 
     from ..core import InfeasibleDeadline, dna_real, fraction_sample_size
@@ -102,6 +104,7 @@ def serve_ppr(args) -> None:
     _print_mesh_plan(res.cores, args.max_lanes)
     print(f"  slot mesh          : "
           f"{f'{args.devices}-chip shard' if args.devices > 1 else 'single chip'}")
+    return executor, res
 
 
 def serve_sim(args) -> None:
@@ -438,9 +441,8 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--block-size", type=int, default=1)
     ap.add_argument("--platform", default=None,
                     choices=["cpu", "gpu", "tpu"],
-                    help="pin jax_platform_name; default lets jax pick the "
-                         "best backend present (the old hardcoded cpu pin "
-                         "is gone — pass --platform cpu to restore it)")
+                    help="pin jax_platform_name; default: JAX's default "
+                         "backend, which JAX_PLATFORMS selects")
     ap.add_argument("--fused", action=argparse.BooleanOptionalAction,
                     default=True,
                     help="fused device-resident hot path (DESIGN.md §7); "
@@ -564,12 +566,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--spares-fraction", type=float, default=0.0,
                     help="daemon: fraction of healthy devices held back "
                          "as re-issue spares (paper's fluctuation margin)")
-    ap.add_argument("--compilation-cache", default="", metavar="DIR",
-                    help="persistent XLA compilation cache directory "
-                         "(DESIGN.md §15): the daemon's second cold start "
-                         "reloads executables instead of recompiling, so "
-                         "the compile surcharge stops being billed against "
-                         "the first jobs' deadlines")
     ap.add_argument("--autotune-cache", default="", metavar="PATH",
                     help="kernel tuning-cache JSON from "
                          "`python -m repro.kernels.autotune` — consulted at "
@@ -583,49 +579,27 @@ def build_parser() -> argparse.ArgumentParser:
                     default=None,
                     help="treat the compilation cache as warm (waive "
                          "--cold-compile); default auto-detects: warm iff "
-                         "--compilation-cache names a non-empty directory")
+                         "the persistent compilation cache directory "
+                         "($JAX_COMPILATION_CACHE_DIR, else "
+                         "<checkout>/.jax_cache) already holds entries")
     return ap
 
 
-def _enable_compilation_cache(path: str) -> bool:
-    """Point JAX's persistent compilation cache at ``path``; returns True
-    when the directory already held entries (a warm start). Thresholds are
-    dropped to zero so even the CPU daemon's small executables persist —
-    the default min-compile-time gate would skip exactly the executables
-    this repo serves."""
-    import os
-
-    entries = (os.path.isdir(path)
-               and any(True for _ in os.scandir(path)))
-    import jax
-
-    try:
-        from jax.experimental.compilation_cache import compilation_cache as cc
-
-        cc.set_cache_dir(path)
-    except Exception:          # noqa: BLE001 — older/newer jax spellings
-        jax.config.update("jax_compilation_cache_dir", path)
-    for opt, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(opt, val)
-        except Exception:      # noqa: BLE001 — knob absent in this jax
-            pass
-    return bool(entries)
-
-
-def main(argv: list[str] | None = None) -> None:
+def main(argv: list[str] | None = None):
+    """Run the CLI; the one-shot PPR pipeline returns its
+    ``(ForaExecutor, DnaResult)`` to in-process callers (``chip_smoke.py``)."""
     args = build_parser().parse_args(argv)
     if args.platform is not None:
         import jax
 
         jax.config.update("jax_platform_name", args.platform)
-    if args.compilation_cache:
-        warm = _enable_compilation_cache(args.compilation_cache)
-        if args.warm_start is None:
-            args.warm_start = warm
+    # the persistent compilation cache is on before the first compile; a
+    # cache that already holds executables is a warm start (DESIGN.md §15)
+    from .compile_cache import cache_entries, enable_compilation_cache
+
     if args.warm_start is None:
-        args.warm_start = False
+        args.warm_start = cache_entries() > 0
+    enable_compilation_cache()
     if args.autotune_cache:
         from pathlib import Path as _Path
 
@@ -639,7 +613,7 @@ def main(argv: list[str] | None = None) -> None:
     if args.daemon:
         serve_daemon(args)
     elif args.workload == "ppr":
-        serve_ppr(args)
+        return serve_ppr(args)
     else:
         serve_sim(args)
 

@@ -68,7 +68,6 @@ def loss_fn_owner_computes(params, cfg: GCNConfig, batch: GraphBatch, mesh):
     all-gather of the (already projected, d_hidden-narrow) source features —
     replacing GSPMD's per-layer psum/permute storm over (n, d) scatters.
     """
-    from ...distributed.ctx import shard_map_compat as shard_map
     from jax.sharding import PartitionSpec as P
 
     D = mesh.shape["data"]
@@ -109,7 +108,7 @@ def loss_fn_owner_computes(params, cfg: GCNConfig, batch: GraphBatch, mesh):
         den = jax.lax.psum(m.sum(), "data")
         return (num / jnp.maximum(den, 1.0))[None]
 
-    loss = shard_map(
+    loss = jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(P("data", None), P(None, "data"), P("data"), P("data"),
                   P("data")),

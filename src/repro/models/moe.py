@@ -95,7 +95,6 @@ def _moe_apply_local_select(params, cfg: MoEConfig, x: jax.Array, mesh):
     single psum of the combined output (each token's k expert contributions
     live on at most k shards). No all-to-all, no scatter-merge all-reduce.
     """
-    from ..distributed.ctx import shard_map_compat as shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, d = x.shape
@@ -157,7 +156,7 @@ def _moe_apply_local_select(params, cfg: MoEConfig, x: jax.Array, mesh):
         return y.reshape(Bl, Sl, dl), aux[None]
 
     b_spec = batch_axes if batch_axes else None
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         kernel, mesh=mesh,
         in_specs=(P(b_spec, None, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

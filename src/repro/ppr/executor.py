@@ -381,6 +381,14 @@ class ForaExecutor:
         bit-parity reference the engine is tested against. Requires
         ``query_seeded`` (otherwise chunk answers depend on composition and
         no cross-batch parity exists)."""
+        ids = list(query_ids)
+        return np.asarray(self.chunk_result(ids).pi)[:len(ids)]
+
+    def chunk_result(self, query_ids: Sequence[int]):
+        """The device-resident :class:`~repro.ppr.fora.FusedForaResult`
+        behind :meth:`answer_chunk` (residual mass and walk lanes too).
+        Its batch is padded to the parity quantum: rows past
+        ``len(query_ids)`` repeat the chunk's own queries."""
         if not (self.fused and self.query_seeded):
             raise ValueError("answer_chunk needs the fused query-seeded path")
         ids = list(query_ids)
@@ -400,11 +408,10 @@ class ForaExecutor:
                                      dtype=np.int32))
             qseeds = jax.device_put(
                 np.ascontiguousarray(np.asarray(run_ids, np.int32)))
-        res = fora_fused(self._device_graph, src, self.params,
-                         self._base_key(), num_walks=self._num_walks,
-                         index=self.walk_index, query_seeds=qseeds,
-                         bulk_rng=self._bulk_rng)
-        return np.asarray(res.pi)[:len(ids)]
+        return fora_fused(self._device_graph, src, self.params,
+                          self._base_key(), num_walks=self._num_walks,
+                          index=self.walk_index, query_seeds=qseeds,
+                          bulk_rng=self._bulk_rng)
 
     def degrade(self, factor: float) -> None:
         """DCAF-style graceful degradation for the *remaining* queries: scale

@@ -282,8 +282,6 @@ def _fora_fused_sharded_exe(mesh, axis: str, num_shards: int, sliced: bool,
     makes donation a pessimisation); callers must pass a copy they own."""
     from jax.sharding import PartitionSpec as P
 
-    from ..distributed.ctx import shard_map_compat
-
     kwargs = dict(alpha=alpha, rmax=rmax, omega=omega, n=n,
                   num_walks=num_walks, num_steps=num_steps,
                   max_push_iters=max_push_iters, force=force,
@@ -311,8 +309,9 @@ def _fora_fused_sharded_exe(mesh, axis: str, num_shards: int, sliced: bool,
         sources_pos = 6
     if seeded:
         in_specs = in_specs + (repl,)
-    mapped = shard_map_compat(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=(repl, repl, repl, repl))
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=(repl, repl, repl, repl),
+                           check_vma=False)
     if donate:
         return jax.jit(mapped, donate_argnums=(sources_pos,))
     return jax.jit(mapped)

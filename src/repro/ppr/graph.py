@@ -59,15 +59,17 @@ def inverse_out_degree(out_degree: np.ndarray) -> np.ndarray:
 
 
 def _default_pad_multiple() -> int:
-    """Lane-alignment floor for the sliced push table: a real TPU chunks the
-    lane axis in 128s (DESIGN.md §8), so widths below 128 only add fold
-    overhead there; interpret/CPU runs keep the cheap 8. Deferred jax import
-    so graph.py stays importable without jax."""
-    try:
-        import jax
-        return 128 if jax.default_backend() == "tpu" else 8
-    except Exception:          # noqa: BLE001 — no jax / no backend yet
-        return 8
+    """Lane-alignment floor for the sliced push table. The TPU Pallas SpMM
+    chunks the lane axis in 128s (DESIGN.md §8), so where it runs, widths
+    below 128 only add fold overhead. Everywhere else the floor is 8: on
+    the CPU, and on the TPU while its push SpMM runs as XLA
+    (``kernels/ops.py`` ``TPU_KERNELS``, ROADMAP S0) — there a 128 floor
+    would only pad, and web-Stanford's table would grow from 3.1M cells to
+    33.7M for 1.8M edges, every one gathered each sweep. Deferred import so
+    graph.py stays importable without jax."""
+    from ..kernels import ops
+
+    return 128 if ops.tpu_kernel("ell_spmm_sliced") else 8
 
 
 class SlicedEll(NamedTuple):
@@ -247,7 +249,8 @@ class Graph:
         * W, the cell count of the resulting (n_virtual, W) table. Ties go to
         the smaller W (less VMEM per row block). ``pad_multiple=None``
         resolves the backend-appropriate lane floor
-        (:func:`_default_pad_multiple`): 128 on real TPU, 8 elsewhere.
+        (:func:`_default_pad_multiple`): 128 where the TPU Pallas SpMM
+        runs, 8 elsewhere.
 
         With an active ``kernels.autotune`` tuning cache and no pinned
         ``pad_multiple``, a measured width for this backend/shape-bucket

@@ -78,7 +78,7 @@ def test_fold_bit_identical_to_host_segment_sum(seed, n, B, width,
         jnp.asarray(sl.neighbors), jnp.asarray(sl.mask),
         jnp.asarray(sl.weights), jnp.asarray(sl.row_map), x,
         None if threshold is None else jnp.asarray(threshold),
-        block_n=block_n)
+        block_n=block_n, interpret=True)
     old = _old_path(sl, x, threshold, block_n=block_n)
     assert np.array_equal(np.asarray(new), np.asarray(old)), \
         "in-kernel fold diverged bitwise from the host segment_sum fold"
@@ -101,7 +101,7 @@ def test_fold_single_virtual_row_per_real_row():
                                                     dtype=np.float32))
     new = ell_spmm_sliced_pallas(
         jnp.asarray(sl.neighbors), jnp.asarray(sl.mask),
-        jnp.asarray(sl.weights), jnp.asarray(sl.row_map), x)
+        jnp.asarray(sl.weights), jnp.asarray(sl.row_map), x, interpret=True)
     assert np.array_equal(np.asarray(new), np.asarray(_old_path(sl, x)))
 
 
@@ -115,7 +115,8 @@ def test_fold_block_n_is_numerics_neutral():
                                                      dtype=np.float32))
     outs = [np.asarray(ell_spmm_sliced_pallas(
         jnp.asarray(sl.neighbors), jnp.asarray(sl.mask),
-        jnp.asarray(sl.weights), jnp.asarray(sl.row_map), x, block_n=bn))
+        jnp.asarray(sl.weights), jnp.asarray(sl.row_map), x, block_n=bn,
+        interpret=True))
         for bn in (16, 64, 256)]
     assert np.array_equal(outs[0], outs[1])
     assert np.array_equal(outs[0], outs[2])

@@ -83,6 +83,16 @@ def test_lemma2_dominates_mean_demand(times, X, p_f):
     assert l2 >= X * stats.t_avg / T - 1e-9
 
 
+def test_mean_of_equal_samples_never_exceeds_their_max():
+    """The float mean of five equal samples rounds 1 ULP above them; the
+    clipped mean keeps Lemma 2's ``t_hat >= t_bar`` precondition."""
+    t = 3.2286664189281677
+    assert np.full(5, t).mean() > t                # the rounding itself
+    stats = RuntimeStats(np.full(5, t))
+    assert stats.t_avg == t == stats.t_max
+    assert lemma2_hoeffding_bound(10, 10 * t, stats, p_f=0.125) > 0
+
+
 def test_bound_report_reduction():
     stats = RuntimeStats(np.array([1.0, 1.5, 2.0]))
     rep = BoundReport.from_stats(100, 100.0, stats)

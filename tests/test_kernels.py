@@ -29,7 +29,7 @@ def test_flash_attention_sweep(B, Sq, Skv, Hq, Hkv, Dh, causal, off, dtype):
     k = jax.random.normal(ks[1], (B, Skv, Hkv, Dh), dtype)
     v = jax.random.normal(ks[2], (B, Skv, Hkv, Dh), dtype)
     out = flash_attention_pallas(q, k, v, causal=causal, q_offset=off,
-                                 block_q=32, block_k=64)
+                                 block_q=32, block_k=64, interpret=True)
     expect = ref.flash_attention_ref(q, k, v, causal=causal, q_offset=off)
     tol = 2e-2 if dtype == jnp.bfloat16 else 3e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -47,7 +47,7 @@ def test_ell_spmv_sweep(n, K, block_n):
     msk = jax.random.bernoulli(ks[1], 0.7, (n, K))
     w = jax.random.normal(ks[2], (n, K))
     x = jax.random.normal(ks[3], (n,))
-    out = ell_spmv_pallas(nbr, msk, w, x, block_n=block_n)
+    out = ell_spmv_pallas(nbr, msk, w, x, block_n=block_n, interpret=True)
     expect = ref.ell_spmv_ref(nbr, msk, x, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-4, rtol=1e-4)
@@ -68,7 +68,8 @@ def test_ell_spmm_sweep(B, n, K, block_n):
     msk = msk.at[0].set(False).at[n // 2].set(False)   # zero-degree rows
     w = jax.random.normal(ks[2], (n, K))
     x = jax.random.normal(ks[3], (B, n))
-    out = ell_spmm_pallas(nbr, msk, w, x, block_n=block_n)
+    out = ell_spmm_pallas(nbr, msk, w, x, block_n=block_n,
+                          interpret=True)
     expect = ref.ell_spmm_ref(nbr, msk, x, w)
     assert out.shape == (B, n)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -87,7 +88,8 @@ def test_ell_spmm_fused_threshold(B):
     w = jax.random.normal(ks[2], (n, K))
     x = jax.random.normal(ks[3], (B, n))
     thr = jnp.abs(jax.random.normal(ks[4], (n,))) * 0.5
-    out = ell_spmm_pallas(nbr, msk, w, x, thr, block_n=64)
+    out = ell_spmm_pallas(nbr, msk, w, x, thr, block_n=64,
+                          interpret=True)
     expect = ref.ell_spmm_ref(nbr, msk, x, w, threshold=thr)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-4, rtol=1e-4)
@@ -105,8 +107,9 @@ def test_ell_spmm_batch1_matches_spmv():
     msk = jax.random.bernoulli(ks[1], 0.7, (n, K))
     w = jax.random.normal(ks[2], (n, K))
     x = jax.random.normal(ks[3], (n,))
-    spmm = ell_spmm_pallas(nbr, msk, w, x[None, :], block_n=32)
-    spmv = ell_spmv_pallas(nbr, msk, w, x, block_n=32)
+    spmm = ell_spmm_pallas(nbr, msk, w, x[None, :], block_n=32,
+                           interpret=True)
+    spmv = ell_spmv_pallas(nbr, msk, w, x, block_n=32, interpret=True)
     np.testing.assert_allclose(np.asarray(spmm[0]), np.asarray(spmv),
                                atol=1e-5, rtol=1e-5)
 
@@ -146,7 +149,8 @@ def test_ell_spmv_is_push_relaxation():
     w = (1.0 / np.maximum(g.out_degree, 1))[nbr] * msk
     x = np.random.default_rng(0).random(g.n).astype(np.float32)
     got = ell_spmv_pallas(jnp.asarray(nbr), jnp.asarray(msk),
-                          jnp.asarray(w.astype(np.float32)), jnp.asarray(x))
+                          jnp.asarray(w.astype(np.float32)), jnp.asarray(x),
+                          interpret=True)
     # reference: dense P^T x via segment sum
     contrib = x[g.edge_src] / np.maximum(g.out_degree, 1)[g.edge_src]
     expect = np.zeros(g.n, np.float32)
@@ -163,7 +167,8 @@ def test_embedding_bag_sweep(V, d, B, L, block_b):
     table = jax.random.normal(ks[0], (V, d))
     ids = jax.random.randint(ks[1], (B, L), 0, V)
     w = jax.random.uniform(ks[2], (B, L))
-    out = embedding_bag_pallas(table, ids, w, block_b=block_b)
+    out = embedding_bag_pallas(table, ids, w, block_b=block_b,
+                               interpret=True)
     expect = ref.embedding_bag_ref(table, ids, w)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                atol=1e-4, rtol=1e-4)
@@ -178,7 +183,7 @@ def test_embedding_bag_matches_din_interest_pooling():
     w = jax.random.uniform(ks[2], (B, L))
     hist = jnp.take(table, ids, axis=0)
     expect = jnp.einsum("bl,bld->bd", w, hist)
-    got = embedding_bag_pallas(table, ids, w)
+    got = embedding_bag_pallas(table, ids, w, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(expect), atol=1e-5)
 
 

@@ -73,9 +73,10 @@ def test_width_heuristic_lane_aligned_and_cheaper():
 
 
 def test_width_floor_follows_backend():
-    """Real-TPU lane floor (ROADMAP follow-up): with the backend reporting
-    TPU the default sliced width snaps to multiples of 128 (the kernel's
-    lane-chunk width); interpret/CPU keeps the cheap 8."""
+    """Real-TPU lane floor (ROADMAP follow-up): where the TPU Pallas SpMM
+    runs, the default sliced width snaps to multiples of 128 (the kernel's
+    lane-chunk width); interpret/CPU, and the TPU's XLA SpMM, keep the
+    cheap 8."""
     import repro.ppr.graph as graph_mod
 
     g = powerlaw_graph(400, seed=1)
@@ -150,7 +151,7 @@ def test_sliced_pallas_matches_ref(n, width, block_n):
     x = jnp.asarray(rng.random((4, g.n)).astype(np.float32))
     args = (jnp.asarray(sl.neighbors), jnp.asarray(sl.mask),
             jnp.asarray(sl.weights), jnp.asarray(sl.row_map), x)
-    got = ell_spmm_sliced_pallas(*args, block_n=block_n)
+    got = ell_spmm_sliced_pallas(*args, block_n=block_n, interpret=True)
     expect = ref.ell_spmm_sliced_ref(args[0], args[1], x, args[2],
                                      row_map=args[3])
     assert got.shape == (4, g.n)
@@ -181,12 +182,14 @@ def test_sliced_equals_dense_spmm():
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.random((2, g.n)).astype(np.float32))
     dense = ell_spmm_pallas(jnp.asarray(nbr), jnp.asarray(msk),
-                            jnp.asarray(w), x, block_n=32)
+                            jnp.asarray(w), x, block_n=32,
+                            interpret=True)
     for width in (8, 64):
         sl = g.ell_in_sliced(width=width)
         sliced = ell_spmm_sliced_pallas(
             jnp.asarray(sl.neighbors), jnp.asarray(sl.mask),
-            jnp.asarray(sl.weights), jnp.asarray(sl.row_map), x, block_n=32)
+            jnp.asarray(sl.weights), jnp.asarray(sl.row_map), x, block_n=32,
+            interpret=True)
         np.testing.assert_allclose(np.asarray(sliced), np.asarray(dense),
                                    atol=1e-5, rtol=1e-5)
 

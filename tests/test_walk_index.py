@@ -188,7 +188,8 @@ def test_walk_endpoint_gather_pallas_matches_ref():
     starts = jnp.asarray(rng.integers(0, n, (B, L)), dtype=jnp.int32)
     weights = jnp.asarray(rng.random((B, L)), dtype=jnp.float32)
     a = ref.walk_endpoint_gather_ref(endpoints, budget, starts, weights)
-    b = walk_endpoint_gather_pallas(endpoints, budget, starts, weights)
+    b = walk_endpoint_gather_pallas(endpoints, budget, starts, weights,
+                                    interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
     # dispatch: force="pallas" exercises interpret mode off-TPU
     c = ops.walk_endpoint_gather(endpoints, budget, starts, weights,
