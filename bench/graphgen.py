@@ -1,0 +1,116 @@
+"""The benchmark's graph generator: a power-law graph at exactly n and m.
+
+A copy of the repository's ``ppr/datasets.py::synthesize`` generator, kept
+here so that no change to the program can move the yardstick, and corrected
+to give the published order and size exactly: lognormal out-degrees with the
+mean m/n, targets drawn from a Zipf-like popularity over node ids mixed with
+a uniform tail, and then more draws until exactly ``m`` distinct edges exist
+(arcs for a directed graph, unordered pairs for an undirected one). Every
+node keeps at least one edge, so no node is dangling and the program adds no
+self-loop: the graph it builds has exactly ``m`` arcs (``2 m`` undirected).
+
+The popularity exponent is fitted to the published largest in-degree: the
+Zipf draws aim ``HUB_MARGIN`` of it at node 0, the most popular node, which
+keeps somewhat less once repeated edges are merged (94.7% of it for the
+web-stanford configuration). A graph whose largest in-degree (degree, for
+an undirected graph) passes the published one is refused: so is one with
+too few arcs per node for repeated arcs to merge enough of node 0's
+draws.
+
+Deterministic per seed. Numpy only.
+"""
+
+from __future__ import annotations
+
+import math
+
+import numpy as np
+
+UNIFORM_SHARE = 0.15  # share of targets drawn uniformly (datasets.py)
+DEGREE_SIGMA = 1.0    # lognormal sigma of the out-degrees (datasets.py)
+HUB_MARGIN = 1.1      # node 0's Zipf draws over the published largest in-degree
+
+
+def zipf_exponent(n: int, m: int, max_in_degree: int) -> float:
+    """The popularity exponent ``a`` at which ``HUB_MARGIN * max_in_degree``
+    of ``m`` target draws land on node 0. A Zipf draw lands on node 0 with
+    probability ``n ** (a - 1)``, and ``1 - UNIFORM_SHARE`` of the targets
+    are Zipf draws."""
+    share = HUB_MARGIN * max_in_degree / ((1.0 - UNIFORM_SHARE) * m)
+    a = 1.0 + math.log(share) / math.log(n)
+    if not 0.0 < a < 1.0:
+        raise ValueError(f"no popularity exponent gives n={n}, m={m} a "
+                         f"largest in-degree of {max_in_degree}")
+    return a
+
+
+def _targets(rng: np.random.Generator, n: int, size: int,
+             a: float) -> np.ndarray:
+    """Zipf-like popular targets over node ids, with a uniform tail."""
+    u = rng.random(size)
+    dst = (n * (u ** (1.0 / (1.0 - a)))).astype(np.int64) % n
+    uniform = rng.integers(0, n, size=size)
+    return np.where(rng.random(size) < UNIFORM_SHARE, uniform, dst)
+
+
+def _no_self_loops(rng: np.random.Generator, n: int, src: np.ndarray,
+                   dst: np.ndarray) -> np.ndarray:
+    """Move a target that equals its source to another node, uniformly."""
+    loop = src == dst
+    shift = rng.integers(1, n, size=int(loop.sum()))
+    dst = dst.copy()
+    dst[loop] = (dst[loop] + shift) % n
+    return dst
+
+
+def _keys(n: int, src: np.ndarray, dst: np.ndarray,
+          directed: bool) -> np.ndarray:
+    if not directed:
+        src, dst = np.minimum(src, dst), np.maximum(src, dst)
+    return src * n + dst
+
+
+def generate(n: int, m: int, *, directed: bool, seed: int,
+             max_in_degree: int) -> tuple[np.ndarray, np.ndarray]:
+    """Edges ``(src, dst)`` (int32) of a graph with exactly ``m`` distinct
+    edges over ``n`` nodes, no self-loop and no in-degree (degree, if
+    undirected) above ``max_in_degree``. For an undirected graph each edge
+    is given once, ``src < dst``. Every node has an edge."""
+    if n < 2 or not n <= m <= n * (n - 1) // (1 if directed else 2):
+        raise ValueError(f"no simple graph with n={n} and m={m}")
+    a = zipf_exponent(n, m, max_in_degree)
+    rng = np.random.default_rng(seed)
+    avg_deg = m / n
+    mu = np.log(avg_deg) - DEGREE_SIGMA ** 2 / 2.0
+    deg = np.maximum(1, rng.lognormal(mu, DEGREE_SIGMA, size=n)).astype(
+        np.int64)
+    deg = np.minimum(deg, max(64, int(16 * avg_deg)))
+    # each node draws its own edges first, so every node has one
+    src = np.repeat(np.arange(n, dtype=np.int64), deg)
+    dst = _no_self_loops(rng, n, src, _targets(rng, n, src.size, a))
+    keys = np.unique(_keys(n, src, dst, directed))
+    # more draws, from sources in proportion to their degree, until m exist
+    p_src = deg / deg.sum()
+    while keys.size < m:
+        need = m - keys.size
+        size = need + need // 4 + 64
+        s = rng.choice(n, size=size, p=p_src)
+        d = _no_self_loops(rng, n, s, _targets(rng, n, size, a))
+        keys = np.unique(np.concatenate([keys, _keys(n, s, d, directed)]))
+    if keys.size > m:
+        # drop the surplus at random, never the first edge of a node
+        lo, hi = keys // n, keys % n
+        ends = np.concatenate([lo, hi]) if not directed else lo
+        _, first = np.unique(ends, return_index=True)
+        keep = np.zeros(keys.size, bool)
+        keep[first % keys.size] = True
+        spare = np.flatnonzero(~keep)
+        drop = rng.choice(spare, size=keys.size - m, replace=False)
+        keys = np.delete(keys, drop)
+    src, dst = keys // n, keys % n
+    ends = dst if directed else np.concatenate([src, dst])
+    top = int(np.bincount(ends, minlength=n).max())
+    if top > max_in_degree:
+        raise ValueError(f"seed {seed} gives a largest in-degree of {top}, "
+                         f"over {max_in_degree}")
+    return src.astype(np.int32), dst.astype(np.int32)
