@@ -14,6 +14,8 @@ Everything runs in this one process:
    power-iteration reference, a COO ``segment_sum`` that shares no code with
    the ELL push or the walks. Every source must meet FORA's guarantee: a
    relative error of at most eps = 0.5 on every target with pi >= 1/n.
+   Beside each, the share of the push's arc reads that left the frontier
+   and the share of the walks' lane-steps a live, weighted lane began.
 
 With ``--chips 4`` it runs only the node-sharded path (``serve --devices
 4``, DESIGN.md §9) and, for the same sources, the one-chip answers it is
@@ -119,6 +121,10 @@ def _check_answers(executor, qids: list[int], label: str) -> np.ndarray:
     pi = np.asarray(res.pi, np.float64)[:k]
     r_sum = np.asarray(res.residual_mass, np.float64)[:k]
     lanes = np.asarray(res.walks_effective)[:k]
+    sweeps = int(res.push_iters)
+    arcs = np.asarray(res.front_arcs, np.float64)[:k]
+    live = np.asarray(res.walk_steps_live, np.float64)[:k]
+    ran = np.asarray(res.walk_steps_run, np.float64)[:k]
     sources = np.array([executor.workload.source_of(q) for q in qids])
     want = np.asarray(ppr_power_iteration(graph, sources, alpha=0.2),
                       np.float64)
@@ -133,6 +139,14 @@ def _check_answers(executor, qids: list[int], label: str) -> np.ndarray:
         print(f"check[{label}] source={src} r_sum={r_sum[i]:.6g} "
               f"walk_lanes={lanes[i]} fora_budget={budget}{short} "
               f"targets_pi>=1/n={int(big.sum())} max_rel_err={err:.4f}")
+        # how much of the work carried weight: the arcs leaving the
+        # frontier of the push_iters * m the sweeps read, and the walk
+        # lane-steps begun by a live, weighted lane
+        print(f"work[{label}] source={src} sweeps={sweeps} "
+              f"front_arcs={arcs[i]:.0f} "
+              f"frontier_arc_share={arcs[i] / (sweeps * graph.m):.4f} "
+              f"walk_steps_live={live[i]:.0f} walk_steps_run={ran[i]:.0f} "
+              f"live_step_share={live[i] / ran[i]:.4f}")
     verdict = "PASS" if worst <= EPSILON else "FAIL"
     print(f"check[{label}] {verdict}: max_rel_err={worst:.4f} "
           f"eps={EPSILON} over {k} sources")

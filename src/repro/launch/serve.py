@@ -13,7 +13,8 @@ a node-sharded mesh of k chips (``ForaExecutor(devices=k)``).
 
     PYTHONPATH=src python -m repro.launch.serve --workload ppr \\
         --dataset web-stanford --queries 512 --deadline 30 --max-cores 64 \\
-        [--platform tpu] [--devices 4] [--ell-layout auto] [--no-fused]
+        [--platform tpu] [--devices 4] [--ell-layout auto] [--no-fused] \\
+        [--profile DIR]
 
 ``--daemon`` switches from the one-shot pipeline to the continuous serving
 runtime (DESIGN.md §10): a seeded Poisson arrival process
@@ -36,6 +37,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+from contextlib import contextmanager
 from pathlib import Path
 
 
@@ -522,6 +524,11 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--cache-ttl-factor", type=float, default=0.0,
                     help="daemon: auto-tune the cache TTL to this multiple "
                          "of the observed update cadence (0 = static TTL)")
+    ap.add_argument("--profile", default="", metavar="DIR",
+                    help="write a jax.profiler trace of the served job "
+                         "under DIR: the device ops with the fora.* scopes "
+                         "and the executor's fora.* host spans on one "
+                         "clock (empty = off)")
     ap.add_argument("--metrics", default="", metavar="PATH",
                     help="daemon: structured metrics sink (DESIGN.md §16) "
                          "— JSONL rows of occupancy/cache/mutation/"
@@ -610,12 +617,34 @@ def main(argv: list[str] | None = None):
         else:
             print(f"autotune cache {args.autotune_cache} not found — "
                   "running with cold defaults")
-    if args.daemon:
-        serve_daemon(args)
-    elif args.workload == "ppr":
-        return serve_ppr(args)
-    else:
-        serve_sim(args)
+    with _profiled(args.profile):
+        if args.daemon:
+            serve_daemon(args)
+        elif args.workload == "ppr":
+            return serve_ppr(args)
+        else:
+            serve_sim(args)
+
+
+@contextmanager
+def _profiled(log_dir: str):
+    """The JAX profiler on around the block, writing under ``log_dir`` (a
+    Perfetto ``*.trace.json.gz`` beside the ``.xplane.pb``); off when
+    ``log_dir`` is empty. Python frames are not traced."""
+    if not log_dir:
+        yield
+        return
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, create_perfetto_trace=True,
+                             profiler_options=options)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+        print(f"profile: trace written under {log_dir}")
 
 
 if __name__ == "__main__":

@@ -164,17 +164,28 @@ class ForaExecutor:
     def _run_block(self, sources: np.ndarray, seed: int,
                    qids: Sequence[int] | None = None) -> None:
         if self.fused:
-            if self.query_seeded and qids is not None:
-                key = self._base_key()
-                qseeds = np.ascontiguousarray(np.asarray(qids, np.int32))
-            else:
-                key = jax.random.PRNGKey(seed)
-                qseeds = None
-            res = fora_fused(self._device_graph, sources, self.params, key,
-                             num_walks=self._num_walks,
-                             index=self.walk_index, query_seeds=qseeds,
-                             bulk_rng=self._bulk_rng)
-            res.pi.block_until_ready()    # the block's single host sync
+            # host spans on the profiler's clock: fora.call holds
+            # fora.stage (here and inside fora_fused), fora.enqueue and
+            # fora.wait
+            with jax.profiler.TraceAnnotation(
+                    "fora.call", qid=int(qids[0]) if qids else seed,
+                    size=len(sources)):
+                with jax.profiler.TraceAnnotation("fora.stage"):
+                    if self.query_seeded and qids is not None:
+                        key = self._base_key()
+                        qseeds = np.ascontiguousarray(
+                            np.asarray(qids, np.int32))
+                    else:
+                        key = jax.random.PRNGKey(seed)
+                        qseeds = None
+                # the module's name, resolved at call time, so that a wrapper
+                # put there sees every call
+                res = fora_fused(self._device_graph, sources, self.params,
+                                 key, num_walks=self._num_walks,
+                                 index=self.walk_index, query_seeds=qseeds,
+                                 bulk_rng=self._bulk_rng)
+                with jax.profiler.TraceAnnotation("fora.wait"):
+                    res.pi.block_until_ready()  # the block's single host sync
         else:
             key = jax.random.PRNGKey(seed)
             res = fora(self.workload.graph, sources, self.params, key)
@@ -226,21 +237,17 @@ class ForaExecutor:
         dispatch path and the DeviceGraph upload."""
         if self._warmed:
             return
+        with jax.profiler.TraceAnnotation("fora.warmup"):
+            self._warmup()
+        self._warmed = True
+
+    def _warmup(self) -> None:
         if self.fused:
-            if self._device_graph is None:
-                # "auto" reuses the graph's cached upload-once mirror; a
-                # forced layout builds its own device copy for this executor
-                mesh = self._build_mesh() if self.devices > 1 else None
-                if self.ell_layout == "auto":
-                    self._device_graph = self.workload.graph.device(mesh=mesh)
-                elif mesh is not None:
-                    self._device_graph = ShardedDeviceGraph.from_graph(
-                        self.workload.graph, mesh, layout=self.ell_layout)
-                else:
-                    self._device_graph = DeviceGraph.from_graph(
-                        self.workload.graph, layout=self.ell_layout)
+            with jax.profiler.TraceAnnotation("fora.upload"):
+                self._upload()
             if self._num_walks is None:
-                self._num_walks = self._calibrate_walk_budget()
+                with jax.profiler.TraceAnnotation("fora.calibrate"):
+                    self._num_walks = self._calibrate_walk_budget()
             if self.index_budget and self.walk_index is None:
                 # pre-draw the walk endpoints once per workload (FORA+,
                 # DESIGN.md §11) — build cost is warmup, never measured time
@@ -268,9 +275,26 @@ class ForaExecutor:
                 size = min(self.block_size, nq)
                 start = min(qid, nq - size)
                 probe = list(range(start, start + size))
-            self._run_block(self._block_sources(probe), seed=qid, qids=probe)
+            with jax.profiler.TraceAnnotation("fora.probe", qid=probe[0]):
+                self._run_block(self._block_sources(probe), seed=qid,
+                                qids=probe)
             self._warmed_sizes.add(len(probe))
-        self._warmed = True
+
+    def _upload(self) -> None:
+        """The graph's device residency, once per executor: "auto" reuses
+        the graph's cached upload-once mirror; a forced layout builds its
+        own device copy for this executor."""
+        if self._device_graph is not None:
+            return
+        mesh = self._build_mesh() if self.devices > 1 else None
+        if self.ell_layout == "auto":
+            self._device_graph = self.workload.graph.device(mesh=mesh)
+        elif mesh is not None:
+            self._device_graph = ShardedDeviceGraph.from_graph(
+                self.workload.graph, mesh, layout=self.ell_layout)
+        else:
+            self._device_graph = DeviceGraph.from_graph(
+                self.workload.graph, layout=self.ell_layout)
 
     def _warm_size(self, size: int) -> None:
         """Compile an executable variant for an unseen batch size (e.g. the

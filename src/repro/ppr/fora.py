@@ -29,9 +29,10 @@ import numpy as np
 from ..kernels import ops
 from .forward_push import forward_push, forward_push_np
 from .graph import DeviceGraph, Graph, ShardedDeviceGraph
-from .random_walk import (_BULK_RNG_ELEMS, lane_streams, residual_walks,
+from .random_walk import (_BULK_RNG_ELEMS, WalkMass, counted_walk_endpoints,
+                          lane_streams, residual_walks,
                           residual_walks_batched, sample_walk_starts,
-                          walk_endpoints, walk_length_for_tail)
+                          walk_length_for_tail)
 
 
 @dataclass(frozen=True)
@@ -121,6 +122,12 @@ class FusedForaResult(NamedTuple):
     push_iters: jax.Array      # () int32, on device
     walks_effective: jax.Array  # (B,) int32 pow2-quantised budgets, on device
     walks_budget: int          # static lane count W the executable was built at
+    # Work counters, (B,) float32 per row, on device. Shares of the work
+    # done: front_arcs / (push_iters * m) of the push's arc reads carried
+    # mass, walk_steps_live / walk_steps_run of the walks' lane-steps.
+    front_arcs: jax.Array      # out-degrees of frontier nodes, over sweeps
+    walk_steps_live: jax.Array  # lane-steps begun by a live, weighted lane
+    walk_steps_run: jax.Array  # lane-steps the walk scan ran
 
 
 def _pow2_ceil_host(v: int) -> int:
@@ -178,10 +185,12 @@ def _fora_fused_impl(in_neighbors, in_mask, in_weights, in_row_map, edge_dst,
     B = sources.shape[0]
     seeds = jnp.zeros((B, n), jnp.float32).at[
         jnp.arange(B), sources].set(1.0)
-    push = forward_push(in_neighbors, in_mask, in_weights, out_degree, seeds,
-                        alpha=alpha, rmax=rmax, n=n,
-                        max_iters=max_push_iters, row_map=in_row_map,
-                        force=force, shard_axis=shard_axis, block_n=block_n)
+    with jax.named_scope("fora.push"):
+        push = forward_push(in_neighbors, in_mask, in_weights, out_degree,
+                            seeds, alpha=alpha, rmax=rmax, n=n,
+                            max_iters=max_push_iters, row_map=in_row_map,
+                            force=force, shard_axis=shard_axis,
+                            block_n=block_n)
     r_sum = push.r.sum(axis=1)                               # (B,)
     # FORA budget ceil(r_sum * omega), quantised UP to the next power of two
     # on device (mirrors the host-side quantisation of fora()) and clipped to
@@ -208,43 +217,79 @@ def _fora_fused_impl(in_neighbors, in_mask, in_weights, in_row_map, edge_dst,
         # the index's per-lane streams
         starts = jax.vmap(lambda r, k: sample_walk_starts(
             r, k, num_walks=num_walks, n=n)[0])(push.r, keys)
-        act = jnp.clip(w_eff, 1, num_walks).astype(push.r.dtype)
-        lane = jnp.arange(num_walks, dtype=jnp.int32)
-        w_all = jnp.where(lane[None, :] < act[:, None],
-                          (r_sum / act)[:, None], 0.0).astype(push.r.dtype)
-        endpoint = ops.walk_endpoint_gather(
-            idx_endpoints, idx_budget, starts[:, :index_lanes],
-            w_all[:, :index_lanes], force=force)
-        live_lo = 0 if index_partial else index_lanes
-        if live_lo < num_walks:
-            live_lanes = jnp.arange(live_lo, num_walks, dtype=jnp.int32)
-            us = lane_streams(idx_key, live_lanes, num_steps)
-            e_live = walk_endpoints(edge_dst, out_offsets, out_degree,
-                                    starts[:, live_lo:], us, alpha=alpha)
-            w_live = w_all[:, live_lo:]
-            if index_partial:
-                # table-covered head cells already contributed above
-                covered = (lane[None, :index_lanes]
-                           < idx_budget[starts[:, :index_lanes]])
-                w_live = w_live.at[:, :index_lanes].set(
-                    jnp.where(covered, 0.0, w_live[:, :index_lanes]))
-            endpoint = endpoint + jax.vmap(lambda e, ww: jax.ops.segment_sum(
-                ww, e, num_segments=n))(e_live, w_live)
+        with jax.named_scope("fora.walk_steps"):
+            walked = _index_walks(
+                idx_endpoints, idx_budget, idx_key, edge_dst, out_offsets,
+                out_degree, starts, r_sum, w_eff, alpha=alpha, n=n,
+                num_walks=num_walks, num_steps=num_steps,
+                index_lanes=index_lanes, index_partial=index_partial,
+                force=force)
     elif shard_axis is None:
-        endpoint = jax.vmap(lambda r, k, a: residual_walks(
+        walked = _walk_parts(jax.vmap(lambda r, k, a: residual_walks(
             edge_dst, out_offsets, out_degree, r, k, alpha=alpha, n=n,
             num_walks=num_walks, num_steps=num_steps, active_walks=a,
-            bulk_rng=bulk))(push.r, keys, w_eff)
+            bulk_rng=bulk))(push.r, keys, w_eff))
     else:
         lanes = num_walks // num_shards           # caller rounds num_walks up
         offset = jax.lax.axis_index(shard_axis) * lanes
-        endpoint = jax.vmap(lambda r, k, a: residual_walks(
+        walked = _walk_parts(jax.vmap(lambda r, k, a: residual_walks(
             edge_dst, out_offsets, out_degree, r, k, alpha=alpha, n=n,
             num_walks=num_walks, num_steps=num_steps, active_walks=a,
             bulk_rng=bulk, lanes=lanes, lane_offset=offset))(
-                push.r, keys, w_eff)
-        endpoint = jax.lax.psum(endpoint, shard_axis)
-    return push.pi + endpoint, r_sum, push.iters, w_eff
+                push.r, keys, w_eff))
+        with jax.named_scope("fora.walk_steps"):
+            # the shards' endpoint masses and step counts, added up, so
+            # every output is replicated as the out_specs declare
+            walked = jax.lax.psum(walked, shard_axis)
+    return (push.pi + walked.mass, r_sum, push.iters, w_eff,
+            push.front_arcs, walked.steps_live, walked.steps_run)
+
+
+def _walk_parts(walked) -> WalkMass:
+    """The batch's walk phase as a :class:`WalkMass`. A walk function that
+    returns its endpoint mass alone (``bench/tests`` put one in place of
+    ``residual_walks`` to leave the walks out) reports no lane-steps."""
+    if isinstance(walked, WalkMass):
+        return walked
+    none = jnp.zeros(walked.shape[:1], jnp.float32)
+    return WalkMass(walked, none, none)
+
+
+def _index_walks(idx_endpoints, idx_budget, idx_key, edge_dst, out_offsets,
+                 out_degree, starts, r_sum, w_eff, *, alpha: float, n: int,
+                 num_walks: int, num_steps: int, index_lanes: int,
+                 index_partial: bool, force: str | None) -> WalkMass:
+    """The index-backed walk phase of a batch (DESIGN.md §11): the covered
+    lanes' endpoints gathered from the table, the shortfall walked live.
+    Only the live lanes count as lane-steps run."""
+    act = jnp.clip(w_eff, 1, num_walks).astype(r_sum.dtype)
+    lane = jnp.arange(num_walks, dtype=jnp.int32)
+    w_all = jnp.where(lane[None, :] < act[:, None],
+                      (r_sum / act)[:, None], 0.0).astype(r_sum.dtype)
+    endpoint = ops.walk_endpoint_gather(
+        idx_endpoints, idx_budget, starts[:, :index_lanes],
+        w_all[:, :index_lanes], force=force)
+    none = jnp.zeros(starts.shape[:1], jnp.float32)
+    live_lo = 0 if index_partial else index_lanes
+    if live_lo == num_walks:
+        return WalkMass(endpoint, none, none)
+    live_lanes = jnp.arange(live_lo, num_walks, dtype=jnp.int32)
+    us = lane_streams(idx_key, live_lanes, num_steps)
+    w_live = w_all[:, live_lo:]
+    if index_partial:
+        # table-covered head cells already contributed above
+        covered = (lane[None, :index_lanes]
+                   < idx_budget[starts[:, :index_lanes]])
+        w_live = w_live.at[:, :index_lanes].set(
+            jnp.where(covered, 0.0, w_live[:, :index_lanes]))
+    e_live, steps_live = counted_walk_endpoints(
+        edge_dst, out_offsets, out_degree, starts[:, live_lo:], us,
+        alpha=alpha, weighted=w_live > 0)
+    endpoint = endpoint + jax.vmap(lambda e, ww: jax.ops.segment_sum(
+        ww, e, num_segments=n))(e_live, w_live)
+    ran = jnp.full(starts.shape[:1], (num_walks - live_lo) * num_steps,
+                   jnp.float32)
+    return WalkMass(endpoint, steps_live, ran)
 
 
 _FUSED_STATICS = ("alpha", "rmax", "omega", "n", "num_walks", "num_steps",
@@ -310,18 +355,19 @@ def _fora_fused_sharded_exe(mesh, axis: str, num_shards: int, sliced: bool,
     if seeded:
         in_specs = in_specs + (repl,)
     mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=(repl, repl, repl, repl),
+                           out_specs=(repl,) * 7,
                            check_vma=False)
     if donate:
         return jax.jit(mapped, donate_argnums=(sources_pos,))
     return jax.jit(mapped)
 
 
-def _fora_fused_sharded(dg: ShardedDeviceGraph, sources, rp: ResolvedFora,
-                        key: jax.Array, *, num_walks: int,
-                        force: str | None, query_seeds=None,
-                        bulk_rng: bool | None = None) -> FusedForaResult:
-    """shard_map dispatch of :func:`fora_fused` over a sharded residency."""
+def _stage_sharded(dg: ShardedDeviceGraph, sources, rp: ResolvedFora,
+                   key: jax.Array, *, num_walks: int,
+                   force: str | None, query_seeds=None,
+                   bulk_rng: bool | None = None):
+    """The shard_map'd executable of :func:`fora_fused` over a sharded
+    residency, its staged arguments and its lane count."""
     steps = walk_length_for_tail(rp.alpha, rp.walk_tail)
     # pow2 budget, then rounded up so every shard gets an equal lane slice.
     # When num_shards is itself a power of two (every TPU slice shape) the
@@ -348,9 +394,7 @@ def _fora_fused_sharded(dg: ShardedDeviceGraph, sources, rp: ResolvedFora,
     args = (dg.edge_dst, dg.out_offsets, dg.out_degree, sources, key)
     if query_seeds is not None:
         args = args + (jnp.asarray(query_seeds).astype(jnp.int32).reshape(-1),)
-    pi, r_sum, iters, w_eff = exe(*table, *args)
-    return FusedForaResult(pi=pi, residual_mass=r_sum, push_iters=iters,
-                           walks_effective=w_eff, walks_budget=num_walks)
+    return partial(exe, *table, *args), num_walks
 
 
 def fora_fused(dg: "DeviceGraph | ShardedDeviceGraph", sources,
@@ -385,6 +429,24 @@ def fora_fused(dg: "DeviceGraph | ShardedDeviceGraph", sources,
     serving engine's bit-parity contract needs. ``bulk_rng`` pins the
     bulk-vs-per-step draw strategy (``None`` = legacy per-call heuristic).
     """
+    with jax.profiler.TraceAnnotation("fora.stage"):
+        run, num_walks = _stage(dg, sources, params, key, num_walks=num_walks,
+                                force=force, index=index,
+                                query_seeds=query_seeds, bulk_rng=bulk_rng)
+    with jax.profiler.TraceAnnotation("fora.enqueue"):
+        pi, r_sum, iters, w_eff, arcs, live, ran = run()
+    return FusedForaResult(pi=pi, residual_mass=r_sum, push_iters=iters,
+                           walks_effective=w_eff, walks_budget=num_walks,
+                           front_arcs=arcs, walk_steps_live=live,
+                           walk_steps_run=ran)
+
+
+def _stage(dg, sources, params: ForaParams, key, *, num_walks, force, index,
+           query_seeds, bulk_rng):
+    """Everything :func:`fora_fused` does before its executable runs:
+    resolve the parameters, convert and upload the sources and seeds, pick
+    the executable. Returns the call, its arguments bound, and the lane
+    count."""
     rp = params.resolve(dg)
     if key is None:
         key = jax.random.PRNGKey(0)
@@ -394,9 +456,9 @@ def fora_fused(dg: "DeviceGraph | ShardedDeviceGraph", sources,
         if index is not None:
             raise ValueError("walk index is single-device only; the sharded "
                              "residency draws its walk lanes per shard")
-        return _fora_fused_sharded(dg, sources, rp, key,
-                                   num_walks=num_walks, force=force,
-                                   query_seeds=query_seeds, bulk_rng=bulk_rng)
+        return _stage_sharded(dg, sources, rp, key, num_walks=num_walks,
+                              force=force, query_seeds=query_seeds,
+                              bulk_rng=bulk_rng)
     num_walks = _pow2_ceil_host(num_walks)
     steps = walk_length_for_tail(rp.alpha, rp.walk_tail)
     index_lanes, index_partial = 0, False
@@ -422,16 +484,14 @@ def fora_fused(dg: "DeviceGraph | ShardedDeviceGraph", sources,
         fused_fn = _fora_fused
     if query_seeds is not None:
         query_seeds = jnp.asarray(query_seeds).astype(jnp.int32).reshape(-1)
-    pi, r_sum, iters, w_eff = fused_fn(
-        dg.in_neighbors, dg.in_mask, dg.in_weights, dg.in_row_map,
+    return partial(
+        fused_fn, dg.in_neighbors, dg.in_mask, dg.in_weights, dg.in_row_map,
         dg.edge_dst, dg.out_offsets, dg.out_degree, sources, key,
         idx_e, idx_b, idx_k, query_seeds,
         alpha=rp.alpha, rmax=rp.rmax, omega=rp.omega, n=dg.n,
         num_walks=num_walks, num_steps=steps, max_push_iters=10_000,
         force=force, index_lanes=index_lanes, index_partial=index_partial,
-        bulk_rng=bulk_rng, block_n=dg.block_n)
-    return FusedForaResult(pi=pi, residual_mass=r_sum, push_iters=iters,
-                           walks_effective=w_eff, walks_budget=num_walks)
+        bulk_rng=bulk_rng, block_n=dg.block_n), num_walks
 
 
 def fora_step_calib(edge_src, edge_dst, out_offsets, out_degree, seeds, key,
@@ -496,4 +556,4 @@ def fora_step(edge_src, edge_dst, out_offsets, out_degree, seeds, key, *,
     walk = jax.vmap(lambda r, k: residual_walks(
         edge_dst, out_offsets, out_degree, r, k, alpha=alpha, n=n,
         num_walks=num_walks, num_steps=num_steps))(push.r, keys)
-    return push.pi + walk
+    return push.pi + walk.mass

@@ -51,12 +51,23 @@ class PushState(NamedTuple):
     pi: jax.Array        # (B, n) reserve (lower-bound PPR mass)
     r: jax.Array         # (B, n) residual
     iters: jax.Array     # () int32
+    front_arcs: jax.Array  # (B,) float32, see PushResult
 
 
 class PushResult(NamedTuple):
     pi: jax.Array        # (B, n)
     r: jax.Array         # (B, n)
     iters: jax.Array     # () number of frontier sweeps executed
+    front_arcs: jax.Array  # (B,) float32 per row: the out-degrees of its
+    #                        frontier nodes, summed over the sweeps (the arcs
+    #                        that carried mass, of the iters * m each row's
+    #                        sweeps read); a float, so no sweep count can
+    #                        overflow it
+
+
+def _front_arcs(front: jax.Array, deg: jax.Array) -> jax.Array:
+    """Arcs leaving each row's frontier in one sweep: (B,) float32."""
+    return jnp.sum(front * deg[None, :], axis=1)
 
 
 @partial(jax.jit, static_argnames=("n", "max_iters", "force", "shard_axis",
@@ -132,12 +143,14 @@ def forward_push(in_neighbors: jax.Array, in_mask: jax.Array,
                 block_n=block_n)
         moved = (1.0 - alpha) * moved
         r = state.r * (1.0 - front) + moved
-        return PushState(pi=pi, r=r, iters=state.iters + 1)
+        return PushState(pi=pi, r=r, iters=state.iters + 1,
+                         front_arcs=state.front_arcs + _front_arcs(front, deg))
 
     init = PushState(pi=jnp.zeros_like(seeds) if pi0 is None else pi0,
-                     r=seeds, iters=jnp.zeros((), jnp.int32))
+                     r=seeds, iters=jnp.zeros((), jnp.int32),
+                     front_arcs=jnp.zeros(seeds.shape[:1], jnp.float32))
     final = jax.lax.while_loop(cond, body, init)
-    return PushResult(pi=final.pi, r=final.r, iters=final.iters)
+    return PushResult(*final)
 
 
 @partial(jax.jit, static_argnames=("n", "max_iters"))
@@ -166,12 +179,14 @@ def forward_push_coo(edge_src: jax.Array, edge_dst: jax.Array,
         moved = jax.ops.segment_sum(
             spread[:, edge_src].T, edge_dst, num_segments=n).T   # (B, n)
         r = state.r * (1.0 - front) + moved
-        return PushState(pi=pi, r=r, iters=state.iters + 1)
+        return PushState(pi=pi, r=r, iters=state.iters + 1,
+                         front_arcs=state.front_arcs + _front_arcs(front, deg))
 
     init = PushState(pi=jnp.zeros_like(seeds), r=seeds,
-                     iters=jnp.zeros((), jnp.int32))
+                     iters=jnp.zeros((), jnp.int32),
+                     front_arcs=jnp.zeros(seeds.shape[:1], jnp.float32))
     final = jax.lax.while_loop(cond, body, init)
-    return PushResult(pi=final.pi, r=final.r, iters=final.iters)
+    return PushResult(*final)
 
 
 def forward_push_np(graph: Graph, sources: np.ndarray, *, alpha: float,

@@ -48,6 +48,15 @@ class WalkResult(NamedTuple):
     walks: int                 # W actually used (static)
 
 
+class WalkMass(NamedTuple):
+    """What :func:`residual_walks` returns for one batch row."""
+
+    mass: jax.Array         # (n,) endpoint mass
+    steps_live: jax.Array   # () float32 lane-steps begun by a live lane that
+    #                         carries weight: the steps that count
+    steps_run: jax.Array    # () float32 lane-steps the scan stepped
+
+
 # one bulk (num_steps, num_walks) int32 draw is ~10x cheaper than per-step
 # RNG calls on CPU, but must not materialise GBs at the max_walks budget:
 # cap the table at 2^25 elements (128 MB int32) and fall back to per-step
@@ -70,6 +79,13 @@ def _advance(edge_dst, out_offsets, deg, stop_bound, pos, alive, u_step):
     nxt = edge_dst[out_offsets[pos] + (u_step % deg[pos])]
     new_alive = jnp.logical_and(alive, jnp.logical_not(stop))
     return jnp.where(new_alive, nxt, pos), new_alive
+
+
+def _live_lanes(alive: jax.Array, weighted: jax.Array | None) -> jax.Array:
+    """Lanes that begin a step alive and carry weight, counted over the
+    last (lane) axis as float32."""
+    live = alive if weighted is None else jnp.logical_and(alive, weighted)
+    return jnp.sum(live, axis=-1, dtype=jnp.float32)
 
 
 def lane_streams(trajectory_key: jax.Array, lane_ids: jax.Array,
@@ -96,17 +112,33 @@ def walk_endpoints(edge_dst: jax.Array, out_offsets: jax.Array,
     trade: trajectories are reused across queries, starts stay per-query),
     and the (n, L) all-nodes grid is how the walk index is built.
     """
+    return counted_walk_endpoints(edge_dst, out_offsets, out_degree, starts,
+                                  us, alpha=alpha)[0]
+
+
+def counted_walk_endpoints(edge_dst: jax.Array, out_offsets: jax.Array,
+                           out_degree: jax.Array, starts: jax.Array,
+                           us: jax.Array, *, alpha: float,
+                           weighted: jax.Array | None = None
+                           ) -> tuple[jax.Array, jax.Array]:
+    """:func:`walk_endpoints`, and the lane-steps begun by a live lane
+    that ``weighted`` (shaped as ``starts``; all lanes when None) marks,
+    counted per leading index of ``starts`` as float32."""
     deg = jnp.maximum(out_degree, 1).astype(jnp.int32)
     bound = _stop_bound(alpha)
     extra = starts.ndim - 1
 
     def step(carry, u_step):
+        pos, alive, live = carry
+        live = live + _live_lanes(alive, weighted)
         u = u_step.reshape((1,) * extra + u_step.shape)
-        return _advance(edge_dst, out_offsets, deg, bound, *carry, u), None
+        return (*_advance(edge_dst, out_offsets, deg, bound, pos, alive, u),
+                live), None
 
-    init = (starts, jnp.ones(starts.shape, bool))
-    (endpos, _), _ = jax.lax.scan(step, init, us)
-    return endpos
+    init = (starts, jnp.ones(starts.shape, bool),
+            jnp.zeros(starts.shape[:-1], jnp.float32))
+    (endpos, _, live), _ = jax.lax.scan(step, init, us)
+    return endpos, live
 
 
 def sample_walk_starts(residual: jax.Array, key: jax.Array, *,
@@ -117,12 +149,13 @@ def sample_walk_starts(residual: jax.Array, key: jax.Array, *,
     same op order), factored out so the index-backed fused path samples
     starts bit-identically to the live path. Returns (starts (num_walks,),
     r_sum ())."""
-    r_sum = residual.sum()
-    csum = jnp.cumsum(residual)
-    k_start, _ = jax.random.split(key)
-    u = jax.random.uniform(k_start, (num_walks,)) * r_sum
-    starts = jnp.searchsorted(csum, u, side="left").astype(jnp.int32)
-    return jnp.clip(starts, 0, n - 1), r_sum
+    with jax.named_scope("fora.walk_starts"):
+        r_sum = residual.sum()
+        csum = jnp.cumsum(residual)
+        k_start, _ = jax.random.split(key)
+        u = jax.random.uniform(k_start, (num_walks,)) * r_sum
+        starts = jnp.searchsorted(csum, u, side="left").astype(jnp.int32)
+        return jnp.clip(starts, 0, n - 1), r_sum
 
 
 @partial(jax.jit, static_argnames=("n", "num_walks", "num_steps", "bulk_rng",
@@ -134,10 +167,12 @@ def residual_walks(edge_dst: jax.Array, out_offsets: jax.Array,
                    active_walks: jax.Array | None = None,
                    bulk_rng: bool | None = None,
                    lanes: int | None = None,
-                   lane_offset: jax.Array | int = 0) -> jax.Array:
+                   lane_offset: jax.Array | int = 0) -> WalkMass:
     """Monte-Carlo estimate of sum_v r(v) * pi(v, t) for one batch row.
 
-    residual: (n,) non-negative. Returns (n,) endpoint mass.
+    residual: (n,) non-negative. Returns the (n,) endpoint mass with the
+    lane-steps the scan ran and those of them begun by a live, weighted
+    lane (:class:`WalkMass`).
 
     ``num_walks`` is the static lane count; ``active_walks`` (traced scalar,
     1 <= active_walks <= num_walks) is the *effective* budget used by the
@@ -160,7 +195,8 @@ def residual_walks(edge_dst: jax.Array, out_offsets: jax.Array,
     counts dividing the pow2 budget keep it unchanged; others widen it) —
     but only lanes [lane_offset, lane_offset + lanes) are advanced through
     the graph, and weights use *global* lane ids so the active_walks cutoff
-    lands on the same walkers. Callers psum the per-shard endpoint masses.
+    lands on the same walkers. Callers psum the per-shard endpoint masses
+    and step counts.
     """
     lanes_local = num_walks if lanes is None else lanes
     # inverse-CDF start sampling proportional to residual — the shared draw
@@ -169,48 +205,59 @@ def residual_walks(edge_dst: jax.Array, out_offsets: jax.Array,
     # the sharded lane slice commutes with it
     starts, r_sum = sample_walk_starts(residual, key,
                                        num_walks=num_walks, n=n)
-    _, k_walk = jax.random.split(key)
-    if lanes is not None:
-        starts = jax.lax.dynamic_slice_in_dim(starts, lane_offset,
-                                              lanes_local)
-
-    deg = jnp.maximum(out_degree, 1).astype(jnp.int32)
-    stop_bound = _stop_bound(alpha)
-
-    def advance(pos, alive, u_step):
-        return _advance(edge_dst, out_offsets, deg, stop_bound,
-                        pos, alive, u_step)
-
-    init = (starts, jnp.ones(lanes_local, bool))
-    if bulk_rng is None:
-        bulk_rng = num_steps * num_walks <= _BULK_RNG_ELEMS
-    if bulk_rng:
-        us = jax.random.randint(k_walk, (num_steps, num_walks), 0, 1 << 30)
+    with jax.named_scope("fora.walk_steps"):
+        _, k_walk = jax.random.split(key)
         if lanes is not None:
-            us = jax.lax.dynamic_slice_in_dim(us, lane_offset, lanes_local,
-                                              axis=1)
+            starts = jax.lax.dynamic_slice_in_dim(starts, lane_offset,
+                                                  lanes_local)
 
-        def step(carry, u_step):
-            return advance(*carry, u_step), None
+        deg = jnp.maximum(out_degree, 1).astype(jnp.int32)
+        stop_bound = _stop_bound(alpha)
+        if active_walks is None:
+            weighted = None
+            weights = jnp.full((lanes_local,), r_sum / num_walks,
+                               residual.dtype)
+        else:
+            act = jnp.clip(active_walks, 1, num_walks).astype(residual.dtype)
+            lane = lane_offset + jnp.arange(lanes_local)   # global lane ids
+            weighted = lane < act
+            weights = jnp.where(weighted, r_sum / act,
+                                0.0).astype(residual.dtype)
 
-        (endpos, _), _ = jax.lax.scan(step, init, us)
-    else:
-        def step_keyed(carry, step_key):
-            u_step = jax.random.randint(step_key, (num_walks,), 0, 1 << 30)
+        def advance(carry, u_step):
+            pos, alive, live = carry
+            live = live + _live_lanes(alive, weighted)
+            return (*_advance(edge_dst, out_offsets, deg, stop_bound,
+                              pos, alive, u_step), live)
+
+        init = (starts, jnp.ones(lanes_local, bool),
+                jnp.zeros((), jnp.float32))
+        if bulk_rng is None:
+            bulk_rng = num_steps * num_walks <= _BULK_RNG_ELEMS
+        if bulk_rng:
+            us = jax.random.randint(k_walk, (num_steps, num_walks), 0,
+                                    1 << 30)
             if lanes is not None:
-                u_step = jax.lax.dynamic_slice_in_dim(u_step, lane_offset,
-                                                      lanes_local)
-            return advance(*carry, u_step), None
+                us = jax.lax.dynamic_slice_in_dim(us, lane_offset,
+                                                  lanes_local, axis=1)
 
-        keys = jax.random.split(k_walk, num_steps)
-        (endpos, _), _ = jax.lax.scan(step_keyed, init, keys)
-    if active_walks is None:
-        weights = jnp.full((lanes_local,), r_sum / num_walks, residual.dtype)
-    else:
-        act = jnp.clip(active_walks, 1, num_walks).astype(residual.dtype)
-        lane = lane_offset + jnp.arange(lanes_local)   # global lane ids
-        weights = jnp.where(lane < act, r_sum / act, 0.0).astype(residual.dtype)
-    return jax.ops.segment_sum(weights, endpos, num_segments=n)
+            def step(carry, u_step):
+                return advance(carry, u_step), None
+
+            (endpos, _, live), _ = jax.lax.scan(step, init, us)
+        else:
+            def step_keyed(carry, step_key):
+                u_step = jax.random.randint(step_key, (num_walks,), 0,
+                                            1 << 30)
+                if lanes is not None:
+                    u_step = jax.lax.dynamic_slice_in_dim(
+                        u_step, lane_offset, lanes_local)
+                return advance(carry, u_step), None
+
+            keys = jax.random.split(k_walk, num_steps)
+            (endpos, _, live), _ = jax.lax.scan(step_keyed, init, keys)
+        mass = jax.ops.segment_sum(weights, endpos, num_segments=n)
+        return WalkMass(mass, live, jnp.float32(lanes_local * num_steps))
 
 
 def residual_walks_batched(graph: Graph, residual: np.ndarray | jax.Array,
@@ -228,7 +275,7 @@ def residual_walks_batched(graph: Graph, residual: np.ndarray | jax.Array,
         jnp.asarray(graph.edge_dst), jnp.asarray(graph.out_offsets),
         jnp.asarray(graph.out_degree), r, k, alpha=alpha, n=graph.n,
         num_walks=num_walks, num_steps=steps, bulk_rng=bulk))
-    return WalkResult(endpoint_mass=fn(residual, keys), walks=num_walks)
+    return WalkResult(endpoint_mass=fn(residual, keys).mass, walks=num_walks)
 
 
 @partial(jax.jit, static_argnames=("n", "num_walks", "num_steps"))

@@ -115,7 +115,7 @@ def _engine_step_impl(in_neighbors, in_mask, in_weights, in_row_map,
     endpoint = jax.vmap(lambda rr, k, a: residual_walks(
         edge_dst, out_offsets, out_degree, rr, k, alpha=alpha, n=n,
         num_walks=num_walks, num_steps=num_steps, active_walks=a,
-        bulk_rng=bulk_rng))(r, keys, w_eff)
+        bulk_rng=bulk_rng))(r, keys, w_eff).mass
     pi = pi + jnp.where(walk_now[:, None], endpoint, 0.0)
     walked = jnp.logical_or(walked, walk_now)
     return pi, r, walked, w_eff, r_sum

@@ -13,6 +13,7 @@ in device memory.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -71,6 +72,13 @@ def tpu_dispatch(monkeypatch):
     """Steer ops' dispatch down its TPU branch while a test traces."""
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     monkeypatch.setattr(ops, "IMPLS", {})
+
+
+def _scopes(compiled) -> set[str]:
+    """The program's ``fora.*`` layer scopes found in the compiled ops'
+    metadata: what a profiler trace of the chip names its ops by."""
+    names = re.findall(r'op_name="([^"]*)"', compiled.as_text())
+    return {s for n in names for s in re.findall(r"fora\.\w+", n)}
 
 
 def _bytes(compiled) -> int:
@@ -133,6 +141,8 @@ def test_fused_step_compiles_for_one_chip(topo, web_stanford, tpu_dispatch):
     assert ops.IMPLS == {"ell_spmm_sliced": "xla"}
     assert "tpu_custom_call" not in compiled.as_text()
     assert _bytes(compiled) < V5E_HBM_BYTES
+    assert _scopes(compiled) == {"fora.push", "fora.walk_starts",
+                                 "fora.walk_steps"}
 
 
 def test_sharded_step_compiles_for_2x2(topo, web_stanford, tpu_dispatch):
@@ -161,3 +171,5 @@ def test_sharded_step_compiles_for_2x2(topo, web_stanford, tpu_dispatch):
     assert ops.IMPLS == {"ell_spmm_sliced": "xla"}
     assert "all-reduce" in compiled.as_text()
     assert _bytes(compiled) < V5E_HBM_BYTES       # per device
+    assert _scopes(compiled) == {"fora.push", "fora.walk_starts",
+                                 "fora.walk_steps"}
