@@ -16,10 +16,15 @@ Everything runs in this one process:
    relative error of at most eps = 0.5 on every target with pi >= 1/n.
    Beside each, the share of the push's arc reads that left the frontier
    and the share of the walks' lane-steps a live, weighted lane began.
+4. **Lockstep.** The same sources, one per call, through the fused program
+   with the lockstep oracle (``lockstep_residual_walks``: every lane
+   stepped every step on draws drawn whole) in place of the compacted
+   walks. The compaction must change no bit: the largest |dpi| reads 0.
 
 With ``--chips 4`` it runs only the node-sharded path (``serve --devices
 4``, DESIGN.md §9) and, for the same sources, the one-chip answers it is
-compared with; both are checked against the reference. The sharded answers
+compared with; both are checked against the reference, and the sharded
+answers against the lockstep oracle. The sharded answers
 need not be bit-identical to the one-chip ones on the TPU: the ``psum``
 adds the shards' partial frames in another order, so each is held to the
 reference at eps (two answers within eps of pi are within 2*eps*pi of each
@@ -35,7 +40,10 @@ when any phase fails, it exits non-zero and prints no such line.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
+import functools
+import importlib
 import json
 import math
 import sys
@@ -155,6 +163,78 @@ def _check_answers(executor, qids: list[int], label: str) -> np.ndarray:
     return pi
 
 
+@contextlib.contextmanager
+def _lockstep_walks():
+    """``fora_fused`` traced afresh with the lockstep oracle as its walk
+    phase: new executables (a new function object for each, so no earlier
+    trace is reused) over ``lockstep_residual_walks``."""
+    import jax
+
+    from repro.ppr.random_walk import lockstep_residual_walks
+
+    fora = importlib.import_module("repro.ppr.fora")
+
+    names = ("residual_walks", "_fora_fused", "_fora_fused_donating",
+             "_fora_fused_sharded_exe")
+    saved = {name: getattr(fora, name) for name in names}
+    impl = functools.wraps(fora._fora_fused_impl)(
+        lambda *a, **k: fora._fora_fused_impl(*a, **k))
+    fora.residual_walks = lockstep_residual_walks
+    fora._fora_fused = jax.jit(impl, static_argnames=fora._FUSED_STATICS)
+    fora._fora_fused_donating = jax.jit(
+        impl, static_argnames=fora._FUSED_STATICS,
+        donate_argnames=("sources",))
+    fora._fora_fused_sharded_exe = saved["_fora_fused_sharded_exe"].__wrapped__
+    try:
+        yield
+    finally:
+        for name, value in saved.items():
+            setattr(fora, name, value)
+
+
+def _check_lockstep(executor, qids: list[int], label: str) -> None:
+    """Each source's fused answer (one per call, as served) against the
+    same call on the lockstep oracle, with the share of the compacted
+    walks' lane-steps that a live, weighted lane began."""
+    from repro.ppr.random_walk import walk_length_for_tail
+
+    fora = importlib.import_module("repro.ppr.fora")
+
+    def answer(q: int):
+        return fora.fora_fused(
+            executor._device_graph,
+            np.array([executor.workload.source_of(q)], np.int32),
+            executor.params, executor._base_key(),
+            num_walks=executor._num_walks,
+            query_seeds=np.array([q], np.int32),
+            bulk_rng=executor._bulk_rng)
+
+    got = [answer(q) for q in qids]
+    with _lockstep_walks():
+        want = [answer(q) for q in qids]
+    # the oracle steps every lane every step: proof that it ran
+    steps = walk_length_for_tail(executor.params.alpha,
+                                 executor.params.walk_tail)
+    if any(float(ref.walk_steps_run[0]) != ref.walks_budget * steps
+           for ref in want):
+        raise SystemExit(f"FAIL: {label} lockstep oracle did not run")
+    worst = 0.0
+    for q, res, ref in zip(qids, got, want):
+        gap = float(np.abs(np.asarray(res.pi) - np.asarray(ref.pi)).max())
+        worst = max(worst, gap)
+        live = float(res.walk_steps_live[0])
+        ran = float(res.walk_steps_run[0])
+        print(f"lockstep[{label}] source={executor.workload.source_of(q)} "
+              f"walk_lanes={int(res.walks_effective[0])} "
+              f"max_abs_dpi={gap:.6g} walk_steps_live={live:.0f} "
+              f"walk_steps_run={ran:.0f} live_step_share={live / ran:.4f}")
+    print(f"lockstep[{label}] max_abs_dpi={worst:.6g} over {len(qids)} "
+          f"sources")
+    if worst != 0.0:
+        raise SystemExit(f"FAIL: {label} answers differ from the lockstep "
+                         f"walks")
+
+
 def _check_walk_gather(n: int) -> None:
     """The walk-index gather kernel against its oracle at the graph's n."""
     import jax
@@ -196,10 +276,12 @@ def run(chips: int) -> None:
         _timed("walk_gather", _check_walk_gather, executor.workload.graph.n)
         _print_impls()
         _timed("check", _check_answers, executor, qids, "1-chip")
+        _timed("lockstep", _check_lockstep, executor, qids, "1-chip")
         return
     sharded = _timed("serve", _serve, chips)
     _print_impls()
     pi_k = _timed("check", _check_answers, sharded, qids, f"{chips}-chip")
+    _timed("lockstep", _check_lockstep, sharded, qids, f"{chips}-chip")
     one_chip = dataclasses.replace(sharded, devices=1)
     pi_1 = _timed("check", _check_answers, one_chip, qids, "1-chip")
     print(f"sharded vs 1-chip: max_abs_diff={np.abs(pi_k - pi_1).max():.6g} "

@@ -127,7 +127,7 @@ class FusedForaResult(NamedTuple):
     # mass, walk_steps_live / walk_steps_run of the walks' lane-steps.
     front_arcs: jax.Array      # out-degrees of frontier nodes, over sweeps
     walk_steps_live: jax.Array  # lane-steps begun by a live, weighted lane
-    walk_steps_run: jax.Array  # lane-steps the walk scan ran
+    walk_steps_run: jax.Array  # lane-steps the walk loop ran
 
 
 def _pow2_ceil_host(v: int) -> int:
