@@ -9,13 +9,19 @@ a uniform tail, and then more draws until exactly ``m`` distinct edges exist
 node keeps at least one edge, so no node is dangling and the program adds no
 self-loop: the graph it builds has exactly ``m`` arcs (``2 m`` undirected).
 
-The popularity exponent is fitted to the published largest in-degree: the
-Zipf draws aim ``HUB_MARGIN`` of it at node 0, the most popular node, which
-keeps somewhat less once repeated edges are merged (94.7% of it for the
-web-stanford configuration). A graph whose largest in-degree (degree, for
-an undirected graph) passes the published one is refused: so is one with
-too few arcs per node for repeated arcs to merge enough of node 0's
-draws.
+The popularity exponent is fitted to the published largest in-degree
+(degree, for an undirected graph): the Zipf draws aim ``HUB_MARGIN`` of it
+at node 0, the most popular node. Where the hub takes a large share of the
+draws, repeated edges merge and bring it under the bound (node 0 keeps
+36,552 of 38,606 for the web-stanford configuration). Where the hub takes a
+small share (DBLP, Pokec, LiveJournal), few of its draws merge: then each
+node over the bound keeps ``bound`` of its edges, and each of the others
+keeps its other end and moves its end on that node to a node under the
+bound, drawn by the same law; merging, topping up and trimming to ``m``
+follow, until no node is over. Where the merges alone bring every node
+under the bound, nothing moves and no more numbers are drawn. Refused: a
+bound that no popularity exponent can aim at, and one under which n nodes
+cannot hold m edges.
 
 Deterministic per seed. Numpy only.
 """
@@ -70,15 +76,86 @@ def _keys(n: int, src: np.ndarray, dst: np.ndarray,
     return src * n + dst
 
 
+def _exactly_m(rng: np.random.Generator, n: int, m: int, keys: np.ndarray,
+               p_src: np.ndarray, a: float, directed: bool) -> np.ndarray:
+    """``keys`` topped up with more draws, from sources in proportion to
+    their degree, until ``m`` exist; then the surplus dropped at random,
+    never the first edge of a node."""
+    while keys.size < m:
+        need = m - keys.size
+        size = need + need // 4 + 64
+        s = rng.choice(n, size=size, p=p_src)
+        d = _no_self_loops(rng, n, s, _targets(rng, n, size, a))
+        keys = np.unique(np.concatenate([keys, _keys(n, s, d, directed)]))
+    if keys.size > m:
+        lo, hi = keys // n, keys % n
+        ends = np.concatenate([lo, hi]) if not directed else lo
+        _, first = np.unique(ends, return_index=True)
+        keep = np.zeros(keys.size, bool)
+        keep[first % keys.size] = True
+        spare = np.flatnonzero(~keep)
+        drop = rng.choice(spare, size=keys.size - m, replace=False)
+        keys = np.delete(keys, drop)
+    return keys
+
+
+def _redraw(rng: np.random.Generator, n: int, src: np.ndarray, a: float,
+            room: np.ndarray) -> np.ndarray:
+    """A target for each of ``src``, by ``_targets``' law and never the
+    source itself, among the nodes where ``room`` holds."""
+    dst = np.empty_like(src)
+    todo = np.arange(src.size)
+    while todo.size:
+        d = _no_self_loops(rng, n, src[todo], _targets(rng, n, todo.size, a))
+        ok = room[d]
+        dst[todo[ok]] = d[ok]
+        todo = todo[~ok]
+    return dst
+
+
+def _move_surplus(rng: np.random.Generator, n: int, src: np.ndarray,
+                  dst: np.ndarray, degree: np.ndarray, bound: int, a: float,
+                  directed: bool) -> np.ndarray:
+    """The keys of the edges once each node over ``bound`` has kept
+    ``bound`` of its edges: each of the others, picked at random, keeps its
+    other end (an arc its source) and gets a new end among the nodes under
+    the bound. Repeated edges are merged."""
+    edge = np.arange(src.size)
+    if directed:
+        end, other = dst, src
+    else:
+        end, other = np.concatenate([src, dst]), np.concatenate([dst, src])
+        edge = np.concatenate([edge, edge])
+    at = np.flatnonzero(degree[end] > bound)       # ends on a node over it
+    order = np.lexsort((rng.random(at.size), end[at]))
+    at = at[order]
+    node = end[at]
+    rank = np.arange(at.size) - np.searchsorted(node, node)
+    at = at[rank < degree[node] - bound]
+    # an edge between two nodes over the bound moves one end at a time
+    moved, first = np.unique(edge[at], return_index=True)
+    kept_end = other[at][first]
+    new_end = _redraw(rng, n, kept_end, a, degree < bound)
+    stay = np.ones(src.size, bool)
+    stay[moved] = False
+    return np.unique(_keys(n, np.concatenate([src[stay], kept_end]),
+                           np.concatenate([dst[stay], new_end]), directed))
+
+
 def generate(n: int, m: int, *, directed: bool, seed: int,
              max_in_degree: int) -> tuple[np.ndarray, np.ndarray]:
     """Edges ``(src, dst)`` (int32) of a graph with exactly ``m`` distinct
     edges over ``n`` nodes, no self-loop and no in-degree (degree, if
     undirected) above ``max_in_degree``. For an undirected graph each edge
-    is given once, ``src < dst``. Every node has an edge."""
+    is given once, ``src < dst``. Every node has an edge (an out-arc, if
+    directed), so none is dangling."""
     if n < 2 or not n <= m <= n * (n - 1) // (1 if directed else 2):
         raise ValueError(f"no simple graph with n={n} and m={m}")
     a = zipf_exponent(n, m, max_in_degree)
+    if (1 if directed else 2) * m > n * max_in_degree:
+        raise ValueError(f"{n} nodes cannot hold {m} edges with no "
+                         f"{'in-degree' if directed else 'degree'} over "
+                         f"{max_in_degree}")
     rng = np.random.default_rng(seed)
     avg_deg = m / n
     mu = np.log(avg_deg) - DEGREE_SIGMA ** 2 / 2.0
@@ -88,29 +165,16 @@ def generate(n: int, m: int, *, directed: bool, seed: int,
     # each node draws its own edges first, so every node has one
     src = np.repeat(np.arange(n, dtype=np.int64), deg)
     dst = _no_self_loops(rng, n, src, _targets(rng, n, src.size, a))
-    keys = np.unique(_keys(n, src, dst, directed))
-    # more draws, from sources in proportion to their degree, until m exist
     p_src = deg / deg.sum()
-    while keys.size < m:
-        need = m - keys.size
-        size = need + need // 4 + 64
-        s = rng.choice(n, size=size, p=p_src)
-        d = _no_self_loops(rng, n, s, _targets(rng, n, size, a))
-        keys = np.unique(np.concatenate([keys, _keys(n, s, d, directed)]))
-    if keys.size > m:
-        # drop the surplus at random, never the first edge of a node
-        lo, hi = keys // n, keys % n
-        ends = np.concatenate([lo, hi]) if not directed else lo
-        _, first = np.unique(ends, return_index=True)
-        keep = np.zeros(keys.size, bool)
-        keep[first % keys.size] = True
-        spare = np.flatnonzero(~keep)
-        drop = rng.choice(spare, size=keys.size - m, replace=False)
-        keys = np.delete(keys, drop)
-    src, dst = keys // n, keys % n
-    ends = dst if directed else np.concatenate([src, dst])
-    top = int(np.bincount(ends, minlength=n).max())
-    if top > max_in_degree:
-        raise ValueError(f"seed {seed} gives a largest in-degree of {top}, "
-                         f"over {max_in_degree}")
-    return src.astype(np.int32), dst.astype(np.int32)
+    keys = _exactly_m(rng, n, m, np.unique(_keys(n, src, dst, directed)),
+                      p_src, a, directed)
+    while True:
+        src, dst = keys // n, keys % n
+        ends = dst if directed else np.concatenate([src, dst])
+        degree = np.bincount(ends, minlength=n)
+        if degree.max() <= max_in_degree:
+            return src.astype(np.int32), dst.astype(np.int32)
+        keys = _exactly_m(rng, n, m,
+                          _move_surplus(rng, n, src, dst, degree,
+                                        max_in_degree, a, directed),
+                          p_src, a, directed)
