@@ -20,6 +20,16 @@ Everything runs in this one process:
    with the lockstep oracle (``lockstep_residual_walks``: every lane
    stepped every step on draws drawn whole) in place of the compacted
    walks. The compaction must change no bit: the largest |dpi| reads 0.
+5. **Walk starts.** The searches that turn a post-push residual's cumsum
+   and the lanes' draws into start nodes, at the served lane count, timed
+   alone on the residuals of ``STARTS_SOURCES`` of the workload's sources:
+   seconds per call (median of ``STARTS_CALLS`` after a warm-up), compile
+   seconds, and the lanes whose start differs from ``jnp.searchsorted``'s:
+   each must lie on a dip of the cumsum, where the binary search answers
+   by the order of its probes. ``merge_sort_back`` is the program's own
+   (``random_walk._count_below``, which ``random_walk.per_query`` runs on
+   the row of a batch of one), and ``_vmapped`` runs it under ``vmap``
+   over one query, on (1, W + n) operands.
 
 With ``--chips 4`` it runs only the node-sharded path (``serve --devices
 4``, DESIGN.md §9) and, for the same sources, the one-chip answers it is
@@ -61,6 +71,8 @@ DEADLINE_S = 600.0   # D&A's deadline T for the QUERIES
 EPSILON = 0.5        # FORA's eps; delta = p_f = 1/n
 CHECKED = 4          # sources compared with the reference
 SEED = 0
+STARTS_SOURCES = 8   # residuals the walk-start searches are compared on
+STARTS_CALLS = 10    # timed calls per search
 
 
 def _compile_clock() -> dict:
@@ -260,6 +272,104 @@ def _check_walk_gather(n: int) -> None:
         raise SystemExit("FAIL: walk_endpoint_gather differs from its oracle")
 
 
+def _start_searches() -> dict:
+    """Candidate searches for the walk starts: (csum, draws) -> each draw's
+    start before the clip to n - 1."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.ppr import random_walk as rw
+
+    def merge_permute_back(csum, u):
+        # the merge of _count_below, then the counts scattered back to lane
+        # order (cumsum entries to the slots past the lanes)
+        lanes = u.shape[0]
+        tags = jnp.concatenate([jnp.arange(lanes, dtype=jnp.int32),
+                                jnp.full(csum.shape, lanes, jnp.int32)])
+        _, tags = jax.lax.sort(
+            (rw._sort_key(jnp.concatenate([u, csum])), tags), num_keys=1,
+            is_stable=True)
+        entry = tags == lanes
+        below = rw._rank(entry)
+        dest = jnp.where(entry, lanes + below - 1, tags)
+        return rw._permute(below, dest)[:lanes]
+
+    def one_row(search):
+        # under vmap over a batch of one query, as the walks ran before
+        # per_query: every operand (1, W + n)
+        return lambda c, u: jax.vmap(search)(c[None], u[None])[0]
+
+    return {
+        "searchsorted_scan": lambda c, u: jnp.searchsorted(c, u),
+        "searchsorted_sort": lambda c, u: jnp.searchsorted(c, u,
+                                                           method="sort"),
+        "merge_sort_back": rw._count_below,
+        "merge_permute_back": merge_permute_back,
+        "merge_sort_back_vmapped": one_row(rw._count_below),
+    }
+
+
+def _off_dips(csum: np.ndarray, got: np.ndarray, want: np.ndarray) -> int:
+    """Lanes whose two starts differ with no cumsum dip (csum[d + 1] <
+    csum[d]) for d in [min - 1, max] of the two: a search at fault."""
+    dips = np.append(np.flatnonzero(np.diff(csum) < 0), csum.size)
+    lo, hi = np.minimum(got, want), np.maximum(got, want)
+    lo, hi = lo[got != want] - 1, hi[got != want]
+    return int((dips[np.searchsorted(dips, lo)] > hi).sum())
+
+
+def _bench_walk_starts(dg, params, sources, key, num_walks: int) -> None:
+    """Time each walk-start search on post-push residuals of ``sources``
+    (source i as query i: its draws from ``fold_in(key, i)``, as served),
+    and count the lanes whose start differs from the binary search's."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.ppr.forward_push import forward_push
+
+    rp = params.resolve(dg)
+    k = len(sources)
+    seeds = jnp.zeros((k, dg.n), jnp.float32).at[
+        jnp.arange(k), jnp.asarray(sources)].set(1.0)
+    push = forward_push(dg.in_neighbors, dg.in_mask, dg.in_weights,
+                        dg.out_degree, seeds, alpha=rp.alpha, rmax=rp.rmax,
+                        n=dg.n, row_map=dg.in_row_map, block_n=dg.block_n)
+
+    @jax.jit
+    def inputs(r, q):
+        # sample_walk_starts' cumsum and draws
+        k_start, _ = jax.random.split(jax.random.fold_in(key, q))
+        u = jax.random.uniform(k_start, (num_walks,)) * r.sum()
+        return jnp.cumsum(r), u
+
+    rows = [inputs(push.r[i], i) for i in range(k)]
+    dips = sum(int(jnp.sum(jnp.diff(c) < 0)) for c, _ in rows)
+    print(f"walk_starts: lanes={num_walks} n={dg.n} sources={k} "
+          f"cumsum_dips={dips}")
+    want = None
+    for name, fn in _start_searches().items():
+        t0 = time.perf_counter()
+        exe = jax.jit(fn).lower(*rows[0]).compile()
+        compile_s = time.perf_counter() - t0
+        got = [exe(c, u).block_until_ready() for c, u in rows]
+        times = []
+        for _ in range(STARTS_CALLS):
+            t0 = time.perf_counter()
+            exe(*rows[0]).block_until_ready()
+            times.append(time.perf_counter() - t0)
+        if want is None:
+            want = got
+        differ = [int(jnp.sum(g != w)) for g, w in zip(got, want)]
+        off = sum(_off_dips(*(np.asarray(x) for x in (c, g, w)))
+                  for (c, _), g, w in zip(rows, got, want))
+        print(f"walk_starts[{name}] s_per_call={np.median(times):.6f} "
+              f"min_s={min(times):.6f} compile_s={compile_s:.2f} "
+              f"lanes_differing={differ} off_dips={off}")
+        if off:
+            raise SystemExit(f"FAIL: walk starts of {name} differ from the "
+                             f"binary search's away from any cumsum dip")
+
+
 def _timed(phase: str, fn, *args):
     """Run one phase and print how long it took."""
     t0 = time.perf_counter()
@@ -277,6 +387,10 @@ def run(chips: int) -> None:
         _print_impls()
         _timed("check", _check_answers, executor, qids, "1-chip")
         _timed("lockstep", _check_lockstep, executor, qids, "1-chip")
+        _timed("walk_starts", _bench_walk_starts, executor._device_graph,
+               executor.params,
+               [executor.workload.source_of(q) for q in range(STARTS_SOURCES)],
+               executor._base_key(), executor._num_walks)
         return
     sharded = _timed("serve", _serve, chips)
     _print_impls()

@@ -30,7 +30,7 @@ from ..kernels import ops
 from .forward_push import forward_push, forward_push_np
 from .graph import DeviceGraph, Graph, ShardedDeviceGraph
 from .random_walk import (_BULK_RNG_ELEMS, WalkMass, counted_walk_endpoints,
-                          lane_streams, residual_walks,
+                          lane_streams, per_query, residual_walks,
                           residual_walks_batched, sample_walk_starts,
                           walk_length_for_tail)
 
@@ -215,8 +215,8 @@ def _fora_fused_impl(in_neighbors, in_mask, in_weights, in_row_map, edge_dst,
         # them (same key split, same op order), endpoints for the covered
         # lanes gathered from the pre-drawn table, shortfall walked live on
         # the index's per-lane streams
-        starts = jax.vmap(lambda r, k: sample_walk_starts(
-            r, k, num_walks=num_walks, n=n)[0])(push.r, keys)
+        starts = per_query(lambda r, k: sample_walk_starts(
+            r, k, num_walks=num_walks, n=n)[0], push.r, keys)
         with jax.named_scope("fora.walk_steps"):
             walked = _index_walks(
                 idx_endpoints, idx_budget, idx_key, edge_dst, out_offsets,
@@ -225,18 +225,18 @@ def _fora_fused_impl(in_neighbors, in_mask, in_weights, in_row_map, edge_dst,
                 index_lanes=index_lanes, index_partial=index_partial,
                 force=force)
     elif shard_axis is None:
-        walked = _walk_parts(jax.vmap(lambda r, k, a: residual_walks(
+        walked = _walk_parts(per_query(lambda r, k, a: residual_walks(
             edge_dst, out_offsets, out_degree, r, k, alpha=alpha, n=n,
             num_walks=num_walks, num_steps=num_steps, active_walks=a,
-            bulk_rng=bulk))(push.r, keys, w_eff))
+            bulk_rng=bulk), push.r, keys, w_eff))
     else:
         lanes = num_walks // num_shards           # caller rounds num_walks up
         offset = jax.lax.axis_index(shard_axis) * lanes
-        walked = _walk_parts(jax.vmap(lambda r, k, a: residual_walks(
+        walked = _walk_parts(per_query(lambda r, k, a: residual_walks(
             edge_dst, out_offsets, out_degree, r, k, alpha=alpha, n=n,
             num_walks=num_walks, num_steps=num_steps, active_walks=a,
-            bulk_rng=bulk, lanes=lanes, lane_offset=offset))(
-                push.r, keys, w_eff))
+            bulk_rng=bulk, lanes=lanes, lane_offset=offset),
+            push.r, keys, w_eff))
         with jax.named_scope("fora.walk_steps"):
             # the shards' endpoint masses and step counts, added up, so
             # every output is replicated as the out_specs declare
@@ -553,7 +553,7 @@ def fora_step(edge_src, edge_dst, out_offsets, out_degree, seeds, key, *,
                             alpha=alpha, rmax=rmax, n=n,
                             max_iters=max_push_iters)
     keys = jax.random.split(key, seeds.shape[0])
-    walk = jax.vmap(lambda r, k: residual_walks(
+    walk = per_query(lambda r, k: residual_walks(
         edge_dst, out_offsets, out_degree, r, k, alpha=alpha, n=n,
-        num_walks=num_walks, num_steps=num_steps))(push.r, keys)
+        num_walks=num_walks, num_steps=num_steps), push.r, keys)
     return push.pi + walk.mass

@@ -4,8 +4,10 @@ CPU FORA runs ceil(r(v) * omega) walks per residual node with geometric
 lengths. TPU adaptation (DESIGN.md deviation 3):
 
 * **Starts**: W walker start nodes are sampled proportional to the residual
-  via inverse-CDF (cumsum + searchsorted) — identical in distribution to
-  FORA's per-node quota in expectation, and W is static for jit.
+  via inverse-CDF (cumsum, then each draw's count of cumsum entries below
+  it, by one sort of the draws merged with the cumsum) — identical in
+  distribution to FORA's per-node quota in expectation, and W is static
+  for jit.
 * **Steps**: each walk takes up to L = ceil(ln(tail)/ln(1-alpha)) steps
   (truncation mass bounded by ``tail``); termination is a Bernoulli(alpha)
   draw per step (geometric length), a stopped walk stays where it is. A
@@ -146,6 +148,17 @@ def counted_walk_endpoints(edge_dst: jax.Array, out_offsets: jax.Array,
     return endpos, live
 
 
+def per_query(fn, *rows):
+    """``jax.vmap(fn)(*rows)`` over the queries' leading axis, except that a
+    batch of one runs ``fn`` on its row and adds the axis back. Vmapped
+    over one query, each (W,) array of the walks would become (1, W),
+    which the TPU lays out in (1, 128) tiles, an eighth of a tile's rows;
+    one row keeps the 1-D layout."""
+    if rows[0].shape[0] == 1:
+        return jax.tree.map(lambda x: x[None], fn(*(x[0] for x in rows)))
+    return jax.vmap(fn)(*rows)
+
+
 def sample_walk_starts(residual: jax.Array, key: jax.Array, *,
                        num_walks: int, n: int
                        ) -> tuple[jax.Array, jax.Array]:
@@ -153,14 +166,22 @@ def sample_walk_starts(residual: jax.Array, key: jax.Array, *,
     exact draw :func:`residual_walks` performs internally (same key split,
     same op order), factored out so the index-backed fused path samples
     starts bit-identically to the live path. Returns (starts (num_walks,),
-    r_sum ())."""
+    r_sum ()).
+
+    A lane's start is the number of cumsum entries strictly below its draw
+    (:func:`_count_below`), clipped to n - 1. On a non-decreasing cumsum
+    that is ``searchsorted(csum, u, side="left")``; but XLA's parallel
+    prefix sum of non-negative float32 can dip by an ulp (``csum[i + 1] <
+    csum[i]``), where a binary search answers by the order of its probes.
+    The starts are a function of the cumsum alone, so every caller on one
+    backend and shape draws the same starts; they differ from the binary
+    search's only on the cumsum's dips."""
     with jax.named_scope("fora.walk_starts"):
         r_sum = residual.sum()
         csum = jnp.cumsum(residual)
         k_start, _ = jax.random.split(key)
         u = jax.random.uniform(k_start, (num_walks,)) * r_sum
-        starts = jnp.searchsorted(csum, u, side="left").astype(jnp.int32)
-        return jnp.clip(starts, 0, n - 1), r_sum
+        return jnp.clip(_count_below(csum, u), 0, n - 1), r_sum
 
 
 def _randint30_at(key: jax.Array, counter: jax.Array) -> jax.Array:
@@ -262,6 +283,31 @@ def _rank(live: jax.Array) -> jax.Array:
                      preferred_element_type=jnp.float32).astype(jnp.int32)
     before = jnp.cumsum(within[:, -1]) - within[:, -1]
     return (within + before[:, None]).reshape(-1)[:size]
+
+
+def _sort_key(x: jax.Array) -> jax.Array:
+    """int32 keys that order finite float32 as ``<`` does (-0.0 and 0.0
+    equal): the TPU compiles an integer sort of millions of elements in
+    about half the time of a float one."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def _count_below(csum: jax.Array, u: jax.Array) -> jax.Array:
+    """For each draw ``u[i]``, the number of entries of ``csum`` below it,
+    as int32, for any order of ``csum``. One stable sort merges the draws
+    with ``csum``, the draws first, so a draw sorts before every entry
+    equal to it; the entries sorted before a draw are then the entries
+    below it, counted by :func:`_rank`. A sort on the lane id puts the
+    counts back in lane order. A binary search costs ceil(log2(n + 1))
+    dependent gathers of every lane; this, two sorts of W + n elements."""
+    lanes = u.shape[0]
+    tags = jnp.concatenate([jnp.arange(lanes, dtype=jnp.int32),
+                            jnp.full(csum.shape, lanes, jnp.int32)])
+    _, tags = jax.lax.sort((_sort_key(jnp.concatenate([u, csum])), tags),
+                           num_keys=1, is_stable=True)
+    _, below = jax.lax.sort((tags, _rank(tags == lanes)), num_keys=1)
+    return below[:lanes]
 
 
 def _permute(x: jax.Array, dest: jax.Array) -> jax.Array:
@@ -390,8 +436,8 @@ def residual_walks(edge_dst: jax.Array, out_offsets: jax.Array,
     lanes_local = num_walks if lanes is None else lanes
     # inverse-CDF start sampling proportional to residual — the shared draw
     # (the index-backed fused path calls the same helper, so its starts are
-    # bit-identical to this live path's); searchsorted is elementwise, so
-    # the sharded lane slice commutes with it
+    # bit-identical to this live path's); a lane's start depends on its own
+    # draw alone, so the sharded lane slice commutes with it
     starts, r_sum = sample_walk_starts(residual, key,
                                        num_walks=num_walks, n=n)
     with jax.named_scope("fora.walk_steps"):
@@ -459,7 +505,7 @@ def lockstep_residual_walks(edge_dst: jax.Array, out_offsets: jax.Array,
 def residual_walks_batched(graph: Graph, residual: np.ndarray | jax.Array,
                            key: jax.Array, *, alpha: float,
                            num_walks: int, tail: float = 1e-4) -> WalkResult:
-    """vmap over the batch axis of residual (B, n)."""
+    """:func:`residual_walks` over the batch axis of residual (B, n)."""
     residual = jnp.asarray(residual)
     if residual.ndim == 1:
         residual = residual[None, :]
@@ -467,11 +513,11 @@ def residual_walks_batched(graph: Graph, residual: np.ndarray | jax.Array,
     B = residual.shape[0]
     bulk = B * steps * num_walks <= _BULK_RNG_ELEMS
     keys = jax.random.split(key, B)
-    fn = jax.vmap(lambda r, k: residual_walks(
+    walked = per_query(lambda r, k: residual_walks(
         jnp.asarray(graph.edge_dst), jnp.asarray(graph.out_offsets),
         jnp.asarray(graph.out_degree), r, k, alpha=alpha, n=graph.n,
-        num_walks=num_walks, num_steps=steps, bulk_rng=bulk))
-    return WalkResult(endpoint_mass=fn(residual, keys).mass, walks=num_walks)
+        num_walks=num_walks, num_steps=steps, bulk_rng=bulk), residual, keys)
+    return WalkResult(endpoint_mass=walked.mass, walks=num_walks)
 
 
 @partial(jax.jit, static_argnames=("n", "num_walks", "num_steps"))

@@ -55,7 +55,7 @@ import numpy as np
 
 from ..ppr.executor import _pad_batch
 from ..ppr.forward_push import forward_push
-from ..ppr.random_walk import _BULK_RNG_ELEMS, residual_walks
+from ..ppr.random_walk import _BULK_RNG_ELEMS, per_query, residual_walks
 from ..ppr.random_walk import walk_length_for_tail
 from .lanes import LaneTask, SimLaneEngine
 
@@ -112,10 +112,10 @@ def _engine_step_impl(in_neighbors, in_mask, in_weights, in_row_map,
     # fixed-shape walk phase over every lane (SPMD cannot skip rows); only
     # lanes walking *now* accumulate their endpoint mass — the mask is the
     # zero-work contract for everyone else
-    endpoint = jax.vmap(lambda rr, k, a: residual_walks(
+    endpoint = per_query(lambda rr, k, a: residual_walks(
         edge_dst, out_offsets, out_degree, rr, k, alpha=alpha, n=n,
         num_walks=num_walks, num_steps=num_steps, active_walks=a,
-        bulk_rng=bulk_rng))(r, keys, w_eff).mass
+        bulk_rng=bulk_rng), r, keys, w_eff).mass
     pi = pi + jnp.where(walk_now[:, None], endpoint, 0.0)
     walked = jnp.logical_or(walked, walk_now)
     return pi, r, walked, w_eff, r_sum

@@ -81,6 +81,13 @@ def _scopes(compiled) -> set[str]:
     return {s for n in names for s in re.findall(r"fora\.\w+", n)}
 
 
+def _sort_shapes(compiled) -> set[tuple[int, ...]]:
+    """The shapes of the operands the compiled program sorts."""
+    shapes = re.findall(r"= \(\w+\[([\d,]*)\][^=]*\bsort\(",
+                        compiled.as_text())
+    return {tuple(int(d) for d in s.split(",")) for s in shapes}
+
+
 def _bytes(compiled) -> int:
     ma = compiled.memory_analysis()
     return (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -143,6 +150,9 @@ def test_fused_step_compiles_for_one_chip(topo, web_stanford, tpu_dispatch):
     assert _bytes(compiled) < V5E_HBM_BYTES
     assert _scopes(compiled) == {"fora.push", "fora.walk_starts",
                                  "fora.walk_steps"}
+    # a batch of one walks on its row (random_walk.per_query): the walk
+    # starts' sorts are 1-D, not (1, W + n) operands tiled (1, 128)
+    assert _sort_shapes(compiled) == {(WALKS + n,)}
 
 
 def test_sharded_step_compiles_for_2x2(topo, web_stanford, tpu_dispatch):
@@ -173,3 +183,5 @@ def test_sharded_step_compiles_for_2x2(topo, web_stanford, tpu_dispatch):
     assert _bytes(compiled) < V5E_HBM_BYTES       # per device
     assert _scopes(compiled) == {"fora.push", "fora.walk_starts",
                                  "fora.walk_steps"}
+    # a batch of eight sorts its walk starts' rows together
+    assert (batch, WALKS + n) in _sort_shapes(compiled)
